@@ -327,7 +327,13 @@ def refine_nested(sched: Schedule) -> Schedule:
     near = jnp.abs(off) < 0.25 * w_s
     parity = (jnp.arange(a.shape[-1]) % 2) == 0
     pair_child = jnp.where(parity, center - beta * w_s, center + beta * w_s)
-    child_s = jnp.where(near, pair_child, 2.0 * center - a_s)
+    # A parent outside its own cell (a schedule whose nodes do not sit in
+    # their weight cells, e.g. secant-refined bounds) would reflect outside
+    # [0, 1]; its child is clipped to the cell (and to 1.0, which an f32
+    # cumsum of the weights can overshoot by an ulp). In-cell parents are
+    # unaffected: their reflection already lies in the cell.
+    reflected = jnp.clip(2.0 * center - a_s, left, jnp.minimum(right, 1.0))
+    child_s = jnp.where(near, pair_child, reflected)
     child = take(child_s, inv)  # parent-aligned storage order
     a2 = jnp.concatenate([a, child], axis=-1)
     w2 = jnp.concatenate([0.5 * w, 0.5 * w], axis=-1)

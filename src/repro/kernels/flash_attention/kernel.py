@@ -27,6 +27,13 @@ Backward pass (two kernels, independent tilings — see docs/attention.md):
 Ragged masking: ``kvlen`` is a (B, 1) int32 of valid K lengths; K positions
 >= kvlen[b] are masked in all kernels (this is also how the wrappers in
 ``ops.py`` make padded sequence lengths exact).
+
+TPU block layout: a block's last two dims must be multiples of (8, 128) or
+the full array dims. Q/K/V tiles squeeze the (batch, head) dims to (bq, D)
+refs; the per-row f32 vectors (``lse``, ``delta``, the running max and
+denominator) are (.., S, 1) columns, so a (bq, 1) tile is legal and
+broadcasts against the (bq, bk) score tile with no relayout; ``kvlen`` is
+read as a scalar from SMEM (the whole (B,) vector, indexed by program id).
 """
 from __future__ import annotations
 
@@ -50,17 +57,30 @@ def _mask(s, *, causal, qi, ki, bq, bk, kvlen):
     return jnp.where(keep, s, NEG_INF), keep
 
 
+def _tile(bs, D):
+    """(B, H, S, D) block with (batch, head) squeezed -> a (bs, D) ref."""
+    return (None, None, bs, D)
+
+
+def _col(bs):
+    """(B, H, S, 1) per-row f32 column block -> a (bs, 1) ref."""
+    return (None, None, bs, 1)
+
+
+_SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)  # kvlen: whole (B,) vector
+
+
 # ------------------------------------------------------------------ forward
 
 
 def _flash_fwd_kernel(
-    q_ref, k_ref, v_ref, kvlen_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+    kvlen_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     *, causal, bq, bk, scale,
 ):
     ki = pl.program_id(3)
     qi = pl.program_id(2)
     nk = pl.num_programs(3)
-    kvlen = kvlen_ref[0, 0]
+    kvlen = kvlen_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -73,24 +93,24 @@ def _flash_fwd_kernel(
 
     @pl.when(live & (ki * bk < kvlen))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        q = q_ref[...].astype(jnp.float32) * scale  # (bq, D)
+        k = k_ref[...].astype(jnp.float32)  # (bk, D)
+        v = v_ref[...].astype(jnp.float32)  # (bk, D)
         s = q @ k.T  # (bq, bk) — MXU
         s, _ = _mask(s, causal=causal, qi=qi, ki=ki, bq=bq, bk=bk, kvlen=kvlen)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + p @ v
         m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(jnp.maximum(l, 1e-30))
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(
@@ -107,7 +127,7 @@ def flash_attention_fwd_pallas(
     block_k: int = 128,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (o, lse): the attention output and the (B, NQ, Sq) f32
+    """Returns (o, lse): the attention output and the (B, NQ, Sq, 1) f32
     per-row logsumexp residual the backward kernels recompute P from."""
     B, NQ, Sq, D = q.shape
     NKV, Sk = k.shape[1], k.shape[2]
@@ -122,26 +142,26 @@ def flash_attention_fwd_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, iq, ik: (b, 0)),
+            _SMEM_WHOLE,
+            pl.BlockSpec(_tile(bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec(_tile(bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec(_tile(bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec(_tile(bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec(_col(bq), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, NQ, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, NQ, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, NQ, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),  # running max m
-            pltpu.VMEM((bq,), jnp.float32),  # running denom l
+            pltpu.VMEM((bq, 1), jnp.float32),  # running max m
+            pltpu.VMEM((bq, 1), jnp.float32),  # running denom l
             pltpu.VMEM((bq, D), jnp.float32),  # running output acc
         ],
         interpret=interpret,
-    )(q, k, v, kvlen)
+    )(kvlen.reshape(B), q, k, v)
 
 
 @functools.partial(
@@ -170,13 +190,13 @@ def flash_attention_pallas(
 
 
 def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvlen_ref, dq_ref, acc_ref,
+    kvlen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
     *, causal, bq, bk, scale,
 ):
     ki = pl.program_id(3)
     qi = pl.program_id(2)
     nk = pl.num_programs(3)
-    kvlen = kvlen_ref[0, 0]
+    kvlen = kvlen_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -186,24 +206,24 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(live & (ki * bk < kvlen))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        do = do_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        lse = lse_ref[0, 0]  # (bq,) f32
-        delta = delta_ref[0, 0]  # (bq,) f32
+        q = q_ref[...].astype(jnp.float32)  # (bq, D)
+        k = k_ref[...].astype(jnp.float32)  # (bk, D)
+        v = v_ref[...].astype(jnp.float32)  # (bk, D)
+        do = do_ref[...].astype(jnp.float32)  # (bq, D)
+        lse = lse_ref[...]  # (bq, 1) f32
+        delta = delta_ref[...]  # (bq, 1) f32
         s = (q @ k.T) * scale
         _, keep = _mask(s, causal=causal, qi=qi, ki=ki, bq=bq, bk=bk, kvlen=kvlen)
         # recompute P from the lse residual; explicit zero (not exp(NEG_INF -
         # lse)) so fully-masked rows with lse ~ NEG_INF stay exactly zero
-        p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
         dp = do @ v.T  # (bq, bk)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         acc_ref[...] += ds @ k
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 @functools.partial(
@@ -214,8 +234,8 @@ def flash_attention_bwd_dq_pallas(
     k: jax.Array,  # (B, NKV, Sk, D)
     v: jax.Array,
     do: jax.Array,  # (B, NQ, Sq, D) output cotangent
-    lse: jax.Array,  # (B, NQ, Sq) f32 forward residual
-    delta: jax.Array,  # (B, NQ, Sq) f32 rowsum(dO * O)
+    lse: jax.Array,  # (B, NQ, Sq, 1) f32 forward residual
+    delta: jax.Array,  # (B, NQ, Sq, 1) f32 rowsum(dO * O)
     kvlen: jax.Array,  # (B, 1) int32
     *,
     causal: bool = True,
@@ -236,23 +256,23 @@ def flash_attention_bwd_dq_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1), lambda b, h, iq, ik: (b, 0)),
+            _SMEM_WHOLE,
+            pl.BlockSpec(_tile(bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec(_tile(bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec(_tile(bk, D), lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec(_tile(bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec(_col(bq), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec(_col(bq), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_specs=pl.BlockSpec(_tile(bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, NQ, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],  # dq accumulator
         interpret=interpret,
-    )(q, k, v, do, lse, delta, kvlen)
+    )(kvlen.reshape(B), q, k, v, do, lse, delta)
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kvlen_ref,
+    kvlen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_acc, dv_acc,
     *, causal, bq, bk, scale,
 ):
@@ -261,7 +281,7 @@ def _flash_bwd_dkv_kernel(
     qi = pl.program_id(4)
     ng = pl.num_programs(3)
     nq = pl.num_programs(4)
-    kvlen = kvlen_ref[0, 0]
+    kvlen = kvlen_ref[pl.program_id(0)]
 
     @pl.when((g == 0) & (qi == 0))
     def _init():
@@ -273,24 +293,24 @@ def _flash_bwd_dkv_kernel(
 
     @pl.when(live & (jk * bk < kvlen))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        do = do_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        lse = lse_ref[0, 0]  # (bq,) f32
-        delta = delta_ref[0, 0]  # (bq,) f32
+        q = q_ref[...].astype(jnp.float32)  # (bq, D)
+        k = k_ref[...].astype(jnp.float32)  # (bk, D)
+        v = v_ref[...].astype(jnp.float32)  # (bk, D)
+        do = do_ref[...].astype(jnp.float32)  # (bq, D)
+        lse = lse_ref[...]  # (bq, 1) f32
+        delta = delta_ref[...]  # (bq, 1) f32
         s = (q @ k.T) * scale
         _, keep = _mask(s, causal=causal, qi=qi, ki=jk, bq=bq, bk=bk, kvlen=kvlen)
-        p = jnp.where(keep, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
         dv_acc[...] += p.T @ do
         dp = do @ v.T
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk_acc[...] += ds.T @ q
 
     @pl.when((g == ng - 1) & (qi == nq - 1))
     def _finalize():
-        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(
@@ -301,8 +321,8 @@ def flash_attention_bwd_dkv_pallas(
     k: jax.Array,  # (B, NKV, Sk, D)
     v: jax.Array,
     do: jax.Array,  # (B, NQ, Sq, D)
-    lse: jax.Array,  # (B, NQ, Sq) f32
-    delta: jax.Array,  # (B, NQ, Sq) f32
+    lse: jax.Array,  # (B, NQ, Sq, 1) f32
+    delta: jax.Array,  # (B, NQ, Sq, 1) f32
     kvlen: jax.Array,  # (B, 1) int32
     *,
     causal: bool = True,
@@ -321,21 +341,23 @@ def flash_attention_bwd_dkv_pallas(
     kernel = functools.partial(
         _flash_bwd_dkv_kernel, causal=causal, bq=bq, bk=bk, scale=D**-0.5
     )
+    kv_index = lambda b, hk, jk, g, iq: (b, hk, jk, 0)  # noqa: E731
+    q_index = functools.partial(_q_index, G=G)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), functools.partial(_q_index, G=G)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, hk, jk, g, iq: (b, hk, jk, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, hk, jk, g, iq: (b, hk, jk, 0)),
-            pl.BlockSpec((1, 1, bq, D), functools.partial(_q_index, G=G)),
-            pl.BlockSpec((1, 1, bq), functools.partial(_row_index, G=G)),
-            pl.BlockSpec((1, 1, bq), functools.partial(_row_index, G=G)),
-            pl.BlockSpec((1, 1), lambda b, hk, jk, g, iq: (b, 0)),
+            _SMEM_WHOLE,
+            pl.BlockSpec(_tile(bq, D), q_index),
+            pl.BlockSpec(_tile(bk, D), kv_index),
+            pl.BlockSpec(_tile(bk, D), kv_index),
+            pl.BlockSpec(_tile(bq, D), q_index),
+            pl.BlockSpec(_col(bq), q_index),
+            pl.BlockSpec(_col(bq), q_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, hk, jk, g, iq: (b, hk, jk, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, hk, jk, g, iq: (b, hk, jk, 0)),
+            pl.BlockSpec(_tile(bk, D), kv_index),
+            pl.BlockSpec(_tile(bk, D), kv_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, NKV, Sk, D), k.dtype),
@@ -346,12 +368,8 @@ def flash_attention_bwd_dkv_pallas(
             pltpu.VMEM((bk, D), jnp.float32),  # dv accumulator
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta, kvlen)
+    )(kvlen.reshape(B), q, k, v, do, lse, delta)
 
 
 def _q_index(b, hk, jk, g, iq, *, G):
     return (b, hk * G + g, iq, 0)
-
-
-def _row_index(b, hk, jk, g, iq, *, G):
-    return (b, hk * G + g, iq)

@@ -4,7 +4,7 @@
 layout in/out, optional per-row valid lengths, sequence padding to block
 multiples (made exact by the kernel's kvlen mask + output slicing), and a
 ``jax.custom_vjp`` whose backward recomputes the probability tile from the
-(B, NQ, Sq) f32 logsumexp residual — differentiating through attention never
+(B, NQ, Sq, 1) f32 logsumexp residual — differentiating through attention never
 materializes the (B, H, S, S) score tensor in either direction.
 
 Residuals kept for backward: q, k, v, o, lse, kvlen — O(B*S*H*D), vs the
@@ -48,7 +48,9 @@ def _flash_fwd(q, k, v, kvlen, causal, block_q, block_k, interpret):
 def _flash_bwd(causal, block_q, block_k, interpret, res, do):
     q, k, v, o, lse, kvlen = res
     # softmax-jacobian diagonal term, shared by the dQ and dK/dV kernels
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    )
     dq = flash_attention_bwd_dq_pallas(
         q, k, v, do, lse, delta, kvlen, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret,

@@ -9,11 +9,16 @@ per K-tile instead of K).
 Grid: (B, F/Ft, K/Kt) — K is the innermost (sequential) dimension so the
 output tile is revisited with carry semantics; f32 accumulation.
 
+Block layout (TPU tiling rule: a block's last two dims are multiples of
+(8, 128) or the full array dims): the batch dim is squeezed, (B, F) operands
+ride as (B, 1, F) with (1, Ft) tiles and (B, K) operands as (B, K, 1) with
+(Kt, 1) tiles, so every kernel body sees 2-D refs.
+
 IDGI (DESIGN.md §8) adds the gradient-direction weighting
 ``acc += Σ_k c_k g_k²`` with ``c_k = w_k ⟨g_k, diff⟩ / ⟨g_k, g_k⟩``. The two
 inner products reduce over ALL of F, which an F-tiled carry grid cannot see
 at once — so the op runs two passes over the same tiling: a dots kernel
-(grid (B, K/Kt, F/Ft), F innermost, carrying the (1, Kt) partial dots) and a
+(grid (B, K/Kt, F/Ft), F innermost, carrying the (Kt, 1) partial dots) and a
 squared-grad accumulation kernel that reuses the riemann carry structure with
 the per-(b, k) coefficient in place of the weight. Both passes stay
 memory-bound single reads of g; g² is fused, never materialized in HBM.
@@ -34,9 +39,9 @@ def _accum_kernel(acc_ref, g_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = acc_ref[...].astype(jnp.float32)
 
-    g = g_ref[...].astype(jnp.float32)  # (1, Kt, Ft)
-    w = w_ref[...].astype(jnp.float32)  # (1, Kt)
-    o_ref[...] += jnp.sum(g * w[..., None], axis=1)  # (1, Ft)
+    g = g_ref[...].astype(jnp.float32)  # (Kt, Ft)
+    w = w_ref[...].astype(jnp.float32)  # (Kt, 1)
+    o_ref[...] += jnp.sum(g * w, axis=0, keepdims=True)  # (1, Ft)
 
 
 def _dots_kernel(g_ref, d_ref, s_ref, p_ref):
@@ -47,10 +52,10 @@ def _dots_kernel(g_ref, d_ref, s_ref, p_ref):
         s_ref[...] = jnp.zeros_like(s_ref)
         p_ref[...] = jnp.zeros_like(p_ref)
 
-    g = g_ref[...].astype(jnp.float32)  # (1, Kt, Ft)
+    g = g_ref[...].astype(jnp.float32)  # (Kt, Ft)
     d = d_ref[...].astype(jnp.float32)  # (1, Ft)
-    s_ref[...] += jnp.sum(g * g, axis=2)  # (1, Kt)
-    p_ref[...] += jnp.sum(g * d[:, None, :], axis=2)
+    s_ref[...] += jnp.sum(g * g, axis=1, keepdims=True)  # (Kt, 1)
+    p_ref[...] += jnp.sum(g * d, axis=1, keepdims=True)
 
 
 def _accum_sq_kernel(acc_ref, g_ref, c_ref, o_ref):
@@ -60,9 +65,9 @@ def _accum_sq_kernel(acc_ref, g_ref, c_ref, o_ref):
     def _init():
         o_ref[...] = acc_ref[...].astype(jnp.float32)
 
-    g = g_ref[...].astype(jnp.float32)  # (1, Kt, Ft)
-    c = c_ref[...].astype(jnp.float32)  # (1, Kt)
-    o_ref[...] += jnp.sum((g * g) * c[..., None], axis=1)  # (1, Ft)
+    g = g_ref[...].astype(jnp.float32)  # (Kt, Ft)
+    c = c_ref[...].astype(jnp.float32)  # (Kt, 1)
+    o_ref[...] += jnp.sum((g * g) * c, axis=0, keepdims=True)  # (1, Ft)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_f", "interpret"))
@@ -79,23 +84,41 @@ def idgi_dots_pallas(
     bk, bf = min(block_k, K), min(block_f, F)
     assert K % bk == 0 and F % bf == 0, (K, bk, F, bf)
     grid = (B, K // bk, F // bf)
-    return pl.pallas_call(
+    col = pl.BlockSpec((None, bk, 1), lambda b, k, f: (b, k, 0))
+    s, p = pl.pallas_call(
         _dots_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk, bf), lambda b, k, f: (b, k, f)),
-            pl.BlockSpec((1, bf), lambda b, k, f: (b, f)),
+            pl.BlockSpec((None, bk, bf), lambda b, k, f: (b, k, f)),
+            pl.BlockSpec((None, 1, bf), lambda b, k, f: (b, 0, f)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk), lambda b, k, f: (b, k)),
-            pl.BlockSpec((1, bk), lambda b, k, f: (b, k)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K), jnp.float32),
-            jax.ShapeDtypeStruct((B, K), jnp.float32),
-        ],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((B, K, 1), jnp.float32)] * 2,
         interpret=interpret,
-    )(grads, diff)
+    )(grads, diff[:, None, :])
+    return s[..., 0], p[..., 0]
+
+
+def _accum_call(kernel, acc, grads, per_step, block_k, block_f, interpret):
+    """The riemann carry grid shared by the weighted and squared passes:
+    acc (B, F) f32; grads (B, K, F); per_step (B, K) -> (B, F) f32."""
+    B, K, F = grads.shape
+    bk, bf = min(block_k, K), min(block_f, F)
+    assert K % bk == 0 and F % bf == 0, (K, bk, F, bf)
+    row = pl.BlockSpec((None, 1, bf), lambda b, f, k: (b, 0, f))
+    out = pl.pallas_call(
+        kernel,
+        grid=(B, F // bf, K // bk),
+        in_specs=[
+            row,
+            pl.BlockSpec((None, bk, bf), lambda b, f, k: (b, k, f)),
+            pl.BlockSpec((None, bk, 1), lambda b, f, k: (b, k, 0)),
+        ],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((B, 1, F), jnp.float32),
+        interpret=interpret,
+    )(acc[:, None, :], grads, per_step[:, :, None])
+    return out[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_f", "interpret"))
@@ -111,22 +134,7 @@ def ig_accum_sq_pallas(
     """acc (B, F) f32; grads (B, K, F); coeff (B, K) -> (B, F) f32.
 
     out = acc + Σ_k coeff_k · g_k² — the IDGI weighting pass (g² fused)."""
-    B, K, F = grads.shape
-    bk, bf = min(block_k, K), min(block_f, F)
-    assert K % bk == 0 and F % bf == 0, (K, bk, F, bf)
-    grid = (B, F // bf, K // bk)
-    return pl.pallas_call(
-        _accum_sq_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bf), lambda b, f, k: (b, f)),
-            pl.BlockSpec((1, bk, bf), lambda b, f, k: (b, k, f)),
-            pl.BlockSpec((1, bk), lambda b, f, k: (b, k)),
-        ],
-        out_specs=pl.BlockSpec((1, bf), lambda b, f, k: (b, f)),
-        out_shape=jax.ShapeDtypeStruct((B, F), jnp.float32),
-        interpret=interpret,
-    )(acc, grads, coeff)
+    return _accum_call(_accum_sq_kernel, acc, grads, coeff, block_k, block_f, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_f", "interpret"))
@@ -140,19 +148,4 @@ def ig_accum_pallas(
     interpret: bool = True,
 ) -> jax.Array:
     """acc (B, F) f32; grads (B, K, F); weights (B, K) -> (B, F) f32."""
-    B, K, F = grads.shape
-    bk, bf = min(block_k, K), min(block_f, F)
-    assert K % bk == 0 and F % bf == 0, (K, bk, F, bf)
-    grid = (B, F // bf, K // bk)
-    return pl.pallas_call(
-        _accum_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bf), lambda b, f, k: (b, f)),
-            pl.BlockSpec((1, bk, bf), lambda b, f, k: (b, k, f)),
-            pl.BlockSpec((1, bk), lambda b, f, k: (b, k)),
-        ],
-        out_specs=pl.BlockSpec((1, bf), lambda b, f, k: (b, f)),
-        out_shape=jax.ShapeDtypeStruct((B, F), jnp.float32),
-        interpret=interpret,
-    )(acc, grads, weights)
+    return _accum_call(_accum_kernel, acc, grads, weights, block_k, block_f, interpret)

@@ -19,6 +19,10 @@ onto two single-pass kernels:
     instead of K read-modify-write round trips. The per-step (B, K, F)
     carry's transpose is an identity (plus the f32 cast) — the quadratic
     (IDGI) class pays no kernel at all on the way back.
+
+Block layout as in ``kernels.ig_accum``: batch dim squeezed, (B, F) operands
+as (B, 1, F) with (1, Ft) tiles, (B, K) operands as (B, K, 1) with (Kt, 1)
+tiles — the TPU tiling rule for a block's last two dims.
 """
 from __future__ import annotations
 
@@ -35,19 +39,13 @@ def _interp(x_ref, b_ref, a_ref):
     # lifted to f32 for the carry add
     x = x_ref[...]  # (1, Ft) input dtype
     b = b_ref[...]  # (1, Ft)
-    a = a_ref[...].astype(x.dtype)  # (1, Kt)
-    xi = b[:, None, :] + a[:, :, None] * (x - b)[:, None, :]  # (1, Kt, Ft)
-    return xi.astype(jnp.float32)
+    a = a_ref[...].astype(x.dtype)  # (Kt, 1)
+    return (b + a * (x - b)).astype(jnp.float32)  # (Kt, Ft)
 
 
-def _interp_add_bcast_kernel(x_ref, b_ref, a_ref, u_ref, o_ref):
-    u = u_ref[...]  # (1, Ft) f32 — broadcast over steps
-    o_ref[...] = (_interp(x_ref, b_ref, a_ref) + u[:, None, :]).astype(o_ref.dtype)
-
-
-def _interp_add_step_kernel(x_ref, b_ref, a_ref, u_ref, o_ref):
-    u = u_ref[...]  # (1, Kt, Ft) f32 — per-step carry
-    o_ref[...] = (_interp(x_ref, b_ref, a_ref) + u).astype(o_ref.dtype)
+def _interp_add_kernel(x_ref, b_ref, a_ref, u_ref, o_ref):
+    # u: (1, Ft) f32 broadcast over steps, or (Kt, Ft) f32 per-step carry
+    o_ref[...] = (_interp(x_ref, b_ref, a_ref) + u_ref[...]).astype(o_ref.dtype)
 
 
 def _accum_cot_kernel(g_ref, o_ref):
@@ -57,7 +55,7 @@ def _accum_cot_kernel(g_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.sum(g_ref[...].astype(jnp.float32), axis=1)  # (1, Ft)
+    o_ref[...] += jnp.sum(g_ref[...].astype(jnp.float32), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_f", "interpret"))
@@ -77,27 +75,23 @@ def interp_add_pallas(
     K = alphas.shape[1]
     bk, bf = min(block_k, K), min(block_f, F)
     assert K % bk == 0 and F % bf == 0, (K, bk, F, bf)
-    grid = (B, K // bk, F // bf)
+    row = pl.BlockSpec((None, 1, bf), lambda b, k, f: (b, 0, f))
+    tile = pl.BlockSpec((None, bk, bf), lambda b, k, f: (b, k, f))
     bcast = carry.ndim == 2
-    kernel = _interp_add_bcast_kernel if bcast else _interp_add_step_kernel
-    carry_spec = (
-        pl.BlockSpec((1, bf), lambda b, k, f: (b, f))
-        if bcast
-        else pl.BlockSpec((1, bk, bf), lambda b, k, f: (b, k, f))
-    )
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        _interp_add_kernel,
+        grid=(B, K // bk, F // bf),
         in_specs=[
-            pl.BlockSpec((1, bf), lambda b, k, f: (b, f)),
-            pl.BlockSpec((1, bf), lambda b, k, f: (b, f)),
-            pl.BlockSpec((1, bk), lambda b, k, f: (b, k)),
-            carry_spec,
+            row,
+            row,
+            pl.BlockSpec((None, bk, 1), lambda b, k, f: (b, k, 0)),
+            row if bcast else tile,
         ],
-        out_specs=pl.BlockSpec((1, bk, bf), lambda b, k, f: (b, k, f)),
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((B, K, F), x.dtype),
         interpret=interpret,
-    )(x, baseline, alphas, carry)
+    )(x[:, None, :], baseline[:, None, :], alphas[:, :, None],
+      carry[:, None, :] if bcast else carry)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "block_f", "interpret"))
@@ -117,12 +111,13 @@ def accum_cot_pallas(
     B, K, F = grads.shape
     bk, bf = min(block_k, K), min(block_f, F)
     assert K % bk == 0 and F % bf == 0, (K, bk, F, bf)
-    grid = (B, F // bf, K // bk)
-    return pl.pallas_call(
+    row = pl.BlockSpec((None, 1, bf), lambda b, f, k: (b, 0, f))
+    out = pl.pallas_call(
         _accum_cot_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bk, bf), lambda b, f, k: (b, k, f))],
-        out_specs=pl.BlockSpec((1, bf), lambda b, f, k: (b, f)),
-        out_shape=jax.ShapeDtypeStruct((B, F), jnp.float32),
+        grid=(B, F // bf, K // bk),
+        in_specs=[pl.BlockSpec((None, bk, bf), lambda b, f, k: (b, k, f))],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((B, 1, F), jnp.float32),
         interpret=interpret,
     )(grads)
+    return out[:, 0, :]

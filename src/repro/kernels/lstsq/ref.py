@@ -29,7 +29,7 @@ def prepare_normal_eqs(
     """(…, N, N), (…, N) → the regularized, mask-pinned system (f32 minimum).
 
     bf16 inputs are upcast to f32 (the class's accumulation dtype); f64
-    rides through under ``jax.experimental.enable_x64``.
+    rides through under ``jax.enable_x64``.
     """
     dt = jnp.promote_types(A.dtype, jnp.float32)
     A = A.astype(dt)

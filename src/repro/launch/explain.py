@@ -30,9 +30,15 @@ attention; ``--workload`` picks what gets explained:
   traffic   mixed-length random token traffic (the default serving sweep)
   prompt    ONE fixed deterministic prompt — prints the per-token
             attribution table (LM prompt attribution)
-  vit       the reduced ViT on a synthetic image — patch-feature requests
-            through the same bucketed engine; prints the top attributed
-            patches on the patch grid (docs/attention.md quickstarts)
+  vit       ViT-S/16 on --requests synthetic images — patch-feature
+            requests through the same bucketed engine; prints the top
+            attributed patches on the patch grid (docs/attention.md)
+
+Models are CPU-sized (``configs.reduced`` / ``reduced_vit``) unless
+``--published`` asks for the config as published. ``run()`` is the whole
+launcher as a function returning the engine and its last results;
+``load_workload`` and ``make_engine`` are its construction code, shared
+with ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -40,6 +46,8 @@ import argparse
 import json
 import os
 import time
+from dataclasses import dataclass, field
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +57,7 @@ from repro.configs import ARCHS, get_config, reduced
 from repro.core.methods import METHODS
 from repro.core.schedule import SCHEDULES
 from repro.models.registry import Model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import ExplainEngine, ExplainRequest
 
 
@@ -118,12 +127,17 @@ def report(engine: ExplainEngine) -> None:
         print(f"  m_used histogram: {dict(sorted(a.m_used.items()))}")
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         formatter_class=argparse.RawDescriptionHelpFormatter,
         epilog=methods_table(),
     )
     ap.add_argument("--arch", default="llama3-8b", choices=sorted(ARCHS))
+    ap.add_argument(
+        "--published", action="store_true",
+        help="serve the config as published (full widths and depth) instead "
+        "of the CPU-sized reduced() / reduced_vit() variant",
+    )
     ap.add_argument(
         "--method", default="ig", choices=sorted(METHODS),
         help="attribution method (see table below)",
@@ -134,6 +148,11 @@ def main() -> int:
     )
     ap.add_argument("--m", type=int, default=64)
     ap.add_argument("--n-int", type=int, default=4)
+    ap.add_argument(
+        "--chunk", type=int, default=0,
+        help="stage-2 steps per scan step (0 = all m at once); bounds the "
+        "folded (B*chunk) batch, i.e. device memory",
+    )
     ap.add_argument(
         "--n-masks", type=int, default=0,
         help="perturbation mask budget P for forward-only methods "
@@ -171,8 +190,14 @@ def main() -> int:
     ap.add_argument(
         "--workload", default="traffic", choices=("traffic", "prompt", "vit"),
         help="traffic = mixed-length token traffic; prompt = one fixed LM "
-        "prompt with a per-token attribution table; vit = reduced-ViT patch "
-        "attribution demo (ignores --arch/--min-seq/--max-seq)",
+        "prompt with a per-token attribution table; vit = ViT-S/16 patch "
+        "attribution over --requests synthetic images (ignores "
+        "--arch/--min-seq/--max-seq)",
+    )
+    ap.add_argument(
+        "--remat", action="store_true",
+        help="recompute each layer in the stage-2 backward pass instead of "
+        "keeping its residuals (deep models at published widths)",
     )
     ap.add_argument(
         "--use-kernels", action="store_true",
@@ -223,8 +248,133 @@ def main() -> int:
         help="per-tenant token-bucket refill rate in req/s "
         "(0 = unlimited; with --scheduler)",
     )
-    args = ap.parse_args()
+    return ap
 
+
+@dataclass
+class Workload:
+    """What the launcher serves: the model, its params and the engine
+    kwargs the workload needs; ``fixed_reqs`` is replayed every round
+    (``None``: fresh mixed-length traffic per round)."""
+
+    cfg: Any
+    params: Any
+    fixed_reqs: Optional[list[ExplainRequest]] = None
+    engine_kwargs: dict = field(default_factory=dict)
+
+
+def vit_requests(cfg, params, n: int, seed: int) -> list[ExplainRequest]:
+    """``n`` synthetic images (uniform pixels from ``seed``), each explained
+    for the class the model predicts."""
+    from repro.models import vit
+
+    imgs = jax.random.uniform(
+        jax.random.PRNGKey(seed), (n, cfg.image_size, cfg.image_size, cfg.channels)
+    )
+    targets = np.asarray(jnp.argmax(jax.jit(vit.forward, static_argnums=0)(
+        cfg, params, imgs), -1))
+    feats = np.asarray(vit.patchify(cfg, imgs), np.float32)
+    return [
+        ExplainRequest(
+            tokens=np.arange(cfg.num_patches, dtype=np.int32),
+            target=int(t),
+            features=f,
+        )
+        for f, t in zip(feats, targets)
+    ]
+
+
+def load_workload(args) -> Workload:
+    """Config (published or CPU-sized), seeded random params, requests."""
+    if args.workload == "vit":
+        from repro.configs.vit import CONFIG, reduced_vit
+        from repro.models import vit
+
+        cfg = CONFIG if args.published else reduced_vit()
+        params = vit.init(cfg, jax.random.PRNGKey(args.seed))
+        reqs = vit_requests(cfg, params, args.requests, args.seed + 1)
+        print(f"vit workload: {cfg.name} {len(reqs)} images x {cfg.num_patches} "
+              f"patches, predicted classes {[r.target for r in reqs]}")
+        return Workload(cfg, params, reqs, {"seq_buckets": (cfg.num_patches,)})
+    cfg = get_config(args.arch)
+    if not args.published:
+        cfg = reduced(cfg)
+    if cfg.frontend or cfg.is_encdec:
+        print(f"note: {cfg.name} frontend is stubbed; explaining token stream only")
+    params = Model(cfg).init(jax.random.PRNGKey(args.seed))
+    fixed_reqs = None
+    if args.workload == "prompt":
+        # one DETERMINISTIC prompt: same tokens every run, target fixed —
+        # the per-token table below is reproducible output
+        prompt = (np.arange(1, 13, dtype=np.int32) * 7) % (cfg.vocab_size - 1) + 1
+        fixed_reqs = [ExplainRequest(tokens=prompt, target=int(prompt[-1]))]
+        print(f"prompt workload: tokens={prompt.tolist()} target={prompt[-1]}")
+    return Workload(cfg, params, fixed_reqs)
+
+
+def make_engine(args, wl: Workload, *, schedule: str = "", mesh=None, **overrides):
+    """The launcher's ExplainEngine for ``args`` (``overrides`` win)."""
+    kw = dict(
+        method=args.method,
+        schedule=schedule or args.schedule,
+        m=args.m,
+        n_int=args.n_int,
+        chunk=args.chunk,
+        mesh=mesh,
+        adaptive=args.adaptive,
+        tol=args.tol,
+        m_max=args.m_max,
+        n_samples=args.n_samples,
+        sigma=args.sigma,
+        n_masks=args.n_masks,
+        fused=args.fused,
+        use_kernels=args.use_kernels,
+        attn=args.attn,
+        autotune=args.autotune,
+        result_cache=args.result_cache * (1 << 20),
+        hop_zero=args.hop_zero,
+        remat=args.remat,
+        **wl.engine_kwargs,
+    )
+    kw.update(overrides)
+    return ExplainEngine(wl.cfg, wl.params, **kw)
+
+
+def make_scheduler(args, engine):
+    """``--scheduler``: the MixedScheduler admission queue (per-row
+    methods only), else None."""
+    if not args.scheduler:
+        return None
+    if engine.n_samples > 1:
+        print("note: --scheduler serves per-row methods only; "
+              f"{args.method} (n_samples={engine.n_samples}) runs direct")
+        return None
+    from repro.serve import MixedScheduler, TenantPolicy
+
+    tenants = (
+        {"default": TenantPolicy(rate=args.tenant_rate)} if args.tenant_rate else None
+    )
+    return MixedScheduler(engine, max_queue=args.max_queue, tenants=tenants)
+
+
+def serve_round(engine, sched, reqs) -> list[dict]:
+    """One round of requests, direct or through the scheduler; returns the
+    results of the requests that were served."""
+    if sched is None:
+        return engine.explain(reqs)
+    tickets = [sched.submit(r) for r in reqs]
+    sched.run_until_idle()
+    rej = sum(t.status.startswith("rejected") for t in tickets)
+    if rej:
+        print(f"  {rej} rejected (backpressure={sched.rejected_backpressure} "
+              f"rate={sched.rejected_rate})")
+    return [t.result for t in tickets if t.result is not None]
+
+
+def run(argv: Optional[Sequence[str]] = None) -> tuple[ExplainEngine, list[dict]]:
+    """The launcher end to end: returns the ``--schedule`` engine and the
+    results of its last round (uniform comparison engines only print)."""
+    args = build_parser().parse_args(argv)
     mesh = None
     if args.mesh:
         from repro.launch.mesh import ensure_host_devices, make_explain_mesh, parse_mesh_arg
@@ -234,72 +384,17 @@ def main() -> int:
         mesh = make_explain_mesh(dp, tp)
         print(f"mesh: data={dp} model={tp} over {jax.device_count()} devices")
 
-    engine_kwargs: dict = {}
-    fixed_reqs = None
-    if args.workload == "vit":
-        from repro.configs.vit import reduced_vit
-        from repro.models import vit
-
-        cfg = reduced_vit()
-        params = vit.init(cfg, jax.random.PRNGKey(args.seed))
-        img = jax.random.uniform(
-            jax.random.PRNGKey(args.seed + 1),
-            (1, cfg.image_size, cfg.image_size, cfg.channels),
-        )
-        target = int(jnp.argmax(vit.forward(cfg, params, img), -1)[0])
-        feats = np.asarray(vit.patchify(cfg, img), np.float32)[0]
-        fixed_reqs = [
-            ExplainRequest(
-                tokens=np.arange(cfg.num_patches, dtype=np.int32),
-                target=target,
-                features=feats,
-            )
-        ]
-        engine_kwargs["seq_buckets"] = (cfg.num_patches,)
-        print(f"vit workload: {cfg.num_patches} patches, predicted class {target}")
-    else:
-        cfg = reduced(get_config(args.arch))
-        if cfg.frontend or cfg.is_encdec:
-            print(f"note: {cfg.name} frontend is stubbed; explaining token stream only")
-        model = Model(cfg)
-        params = model.init(jax.random.PRNGKey(args.seed))
-        if args.workload == "prompt":
-            # one DETERMINISTIC prompt: same tokens every run, target fixed —
-            # the per-token table below is reproducible output
-            prompt = (np.arange(1, 13, dtype=np.int32) * 7) % (cfg.vocab_size - 1) + 1
-            fixed_reqs = [ExplainRequest(tokens=prompt, target=int(prompt[-1]))]
-            print(f"prompt workload: tokens={prompt.tolist()} target={prompt[-1]}")
+    wl = load_workload(args)
+    cfg = wl.cfg
     rng = np.random.default_rng(args.seed)
-
-    out = None
     compare = (args.schedule,) if args.schedule == "uniform" else (args.schedule, "uniform")
     if METHODS[args.method].forward_only:
         # perturbation methods never touch the interpolation schedule — one
         # pass, no uniform comparison leg
         compare = (args.schedule,)
+    primary = None
     for sched_name in compare:
-        engine = ExplainEngine(
-            cfg,
-            params,
-            method=args.method,
-            schedule=sched_name,
-            m=args.m,
-            n_int=args.n_int,
-            mesh=mesh,
-            adaptive=args.adaptive,
-            tol=args.tol,
-            m_max=args.m_max,
-            n_samples=args.n_samples,
-            sigma=args.sigma,
-            n_masks=args.n_masks,
-            fused=args.fused,
-            use_kernels=args.use_kernels,
-            attn=args.attn,
-            autotune=args.autotune,
-            result_cache=args.result_cache * (1 << 20),
-            hop_zero=args.hop_zero,
-            **engine_kwargs,
-        )
+        engine = make_engine(args, wl, schedule=sched_name, mesh=mesh)
         # the warm state belongs to the primary --schedule engine only; the
         # sweep's comparison engines would just warn about a context mismatch
         if args.warm_state and sched_name == args.schedule:
@@ -322,41 +417,20 @@ def main() -> int:
             + (" autotuned" if args.autotune else "")
         print(f"method={args.method} schedule={sched_name} {mode}{samples}{flags} "
               f"traffic={args.rounds}x{args.requests} reqs S∈[{args.min_seq},{args.max_seq}]")
-        sched = None
-        if args.scheduler and engine.n_samples == 1:
-            from repro.serve import MixedScheduler, TenantPolicy
-
-            tenants = (
-                {"default": TenantPolicy(rate=args.tenant_rate)}
-                if args.tenant_rate
-                else None
-            )
-            sched = MixedScheduler(engine, max_queue=args.max_queue, tenants=tenants)
-        elif args.scheduler:
-            print("note: --scheduler serves per-row methods only; "
-                  f"{args.method} (n_samples={engine.n_samples}) runs direct")
+        sched = make_scheduler(args, engine)
+        out: list[dict] = []
         for rnd in range(args.rounds):
             reqs = (
-                fixed_reqs
-                if fixed_reqs is not None
+                wl.fixed_reqs
+                if wl.fixed_reqs is not None
                 else make_traffic(cfg, args.requests, args.min_seq, args.max_seq, rng)
             )
             t0 = time.perf_counter()
-            if sched is not None:
-                tickets = [sched.submit(r) for r in reqs]
-                sched.run_until_idle()
-                out = [t.result for t in tickets if t.result is not None]
-                rej = sum(t.status.startswith("rejected") for t in tickets)
-                if rej:
-                    print(f"  round {rnd}: {rej} rejected "
-                          f"(backpressure={sched.rejected_backpressure} "
-                          f"rate={sched.rejected_rate})")
-                if not out:
-                    print(f" round {rnd}: all {len(reqs)} requests rejected")
-                    continue
-            else:
-                out = engine.explain(reqs)
+            out = serve_round(engine, sched, reqs)
             wall = time.perf_counter() - t0
+            if not out:
+                print(f" round {rnd}: all {len(reqs)} requests rejected")
+                continue
             deltas = [o["delta"] for o in out]
             line = (f" round {rnd}: wall={wall:.2f}s mean_delta={np.mean(deltas):.5f} "
                     f"max_delta={np.max(deltas):.5f}")
@@ -365,18 +439,23 @@ def main() -> int:
                          f" conv={sum(o.get('converged', False) for o in out)}/{len(out)}")
             print(line)
         report(engine)
-        if args.warm_state and sched_name == args.schedule:
-            from repro.serve import save_warm_state
+        if sched_name == args.schedule:
+            primary = (engine, out)
+            if args.warm_state:
+                from repro.serve import save_warm_state
 
-            save_warm_state(engine, args.warm_state)
-            with open(os.path.join(args.warm_state, "manifest.json")) as fh:
-                n_saved = json.load(fh)["n_executables"]
-            print(f"warm state: saved {n_saved} executables "
-                  f"to {args.warm_state}")
+                save_warm_state(engine, args.warm_state)
+                with open(os.path.join(args.warm_state, "manifest.json")) as fh:
+                    n_saved = json.load(fh)["n_executables"]
+                print(f"warm state: saved {n_saved} executables "
+                      f"to {args.warm_state}")
+    engine, out = primary
+    if not out:
+        return primary
     scores = np.asarray(out[0]["token_scores"])
     if args.workload == "prompt":
         print("per-token attribution (pos, token, score):")
-        for i, (t, s) in enumerate(zip(fixed_reqs[0].tokens, scores)):
+        for i, (t, s) in enumerate(zip(wl.fixed_reqs[0].tokens, scores)):
             print(f"  {i:3d} {int(t):6d} {s:+.6f}")
     elif args.workload == "vit":
         g = cfg.image_size // cfg.patch_size
@@ -389,6 +468,12 @@ def main() -> int:
     else:
         top = np.argsort(-np.abs(scores))[:5]
         print("top-5 attributed positions (last round, req 0):", top)
+    return primary
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    enable_compile_cache()
+    run(argv)
     return 0
 
 

@@ -18,17 +18,29 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis Auto.
+
+    ``make_mesh`` defaults to Explicit axes, under which
+    ``with_sharding_constraint`` (``sharding.context``) and the pjit-style
+    ``in_shardings`` of the explain executables are refused; the whole stack
+    is written for compiler-propagated (Auto) shardings.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many real devices exist (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def parse_mesh_arg(spec: str) -> tuple[int, int]:
@@ -70,4 +82,4 @@ def make_explain_mesh(dp: int, tp: int = 1):
     (``repro.sharding.explain_specs``); ``model`` is plumbed for backbone
     tensor parallelism and may be 1.
     """
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return _auto_mesh((dp, tp), ("data", "model"))

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_config, reduced
 from repro.models.registry import Model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import ServeEngine
 
 
@@ -154,6 +155,9 @@ def run_mixed(cfg, params, args) -> int:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b", choices=sorted(ARCHS))
+    ap.add_argument("--published", action="store_true",
+                    help="serve the config as published instead of the "
+                    "CPU-sized reduced() variant")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--tokens", type=int, default=32)
@@ -180,7 +184,10 @@ def main() -> int:
                     "without a queue slot (--mixed; docs/caching.md)")
     args = ap.parse_args()
 
-    cfg = reduced(get_config(args.arch))
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.published:
+        cfg = reduced(cfg)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))
     if args.mixed:

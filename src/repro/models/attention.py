@@ -2,9 +2,10 @@
 
 Layouts:  q (B, S, NQ, D)   k/v (B, S, NKV, D)   grouped as NQ = NKV * G.
 The blocked paths never materialize an (S, S) score matrix — they are the
-pure-jnp counterpart of the Pallas flash kernel in ``repro.kernels``; the XLA
-path is what the multi-pod dry-run lowers (Pallas-TPU does not lower on the
-CPU placeholder backend), and the kernel is validated in interpret mode.
+pure-jnp counterpart of the Pallas flash kernel in ``repro.kernels``, which
+``attn_impl="flash"`` selects. The kernel runs compiled on a TPU and in
+interpret mode on the CPU test backend (``kernels.common.default_interpret``);
+``tests/test_tpu_compile.py`` compiles it for a described v5e chip.
 """
 from __future__ import annotations
 

@@ -73,7 +73,7 @@ class Model:
 
         return f
 
-    def target_logprob_at_fn(self, params):
+    def target_logprob_at_fn(self, params, *, remat: bool = False):
         """Per-example-position variant for shape-bucketed serving.
 
         Returns f(embeds, aux) -> (B,) with aux = {"target": (B,) token ids,
@@ -83,13 +83,16 @@ class Model:
         threads per-row lengths so the kernel's kvlen block-skip does no work
         on padding (the XLA path needs no mask: causal right-padding is
         already exact, and leaving it unmasked keeps its HLO — and the
-        hotpath bytes baselines — unchanged).
+        hotpath bytes baselines — unchanged). ``remat`` recomputes each
+        layer period in the backward pass instead of keeping its residuals.
         """
         flash = getattr(self.cfg, "attn_impl", "auto") == "flash"
 
         def f(e: jax.Array, aux: dict) -> jax.Array:
             lengths = aux["pos"] + 1 if flash else None
-            h, _ = lm.hidden_from_embeds(self.cfg, params, e, lengths=lengths)
+            h, _ = lm.hidden_from_embeds(
+                self.cfg, params, e, lengths=lengths, remat=remat
+            )
             rows = jnp.arange(e.shape[0])
             lg = lm.logits(self.cfg, params, h[rows, aux["pos"]]).astype(jnp.float32)
             return jax.nn.log_softmax(lg, axis=-1)[rows, aux["target"]]

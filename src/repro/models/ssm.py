@@ -114,7 +114,11 @@ def _ssd(p: dict, u: jax.Array, cfg: ArchConfig, eps: float, return_state: bool)
     L = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (nc, B, l, s, H)
     l_idx = jnp.arange(cl)
     causal = l_idx[:, None] >= l_idx[None, :]
-    L = jnp.where(causal[None, None, :, :, None], jnp.exp(L), 0.0)
+    # mask BEFORE the exp: above the diagonal cum_l - cum_s > 0 grows with
+    # the chunk (about 177 at chunk 256 with unit decay), exp overflows to
+    # inf, and a where after the exp turns that into 0 * inf = NaN in the
+    # backward pass. exp(-inf) is 0 with a zero gradient.
+    L = jnp.exp(jnp.where(causal[None, None, :, :, None], L, -jnp.inf))
     xdt = xc.astype(jnp.float32) * (dA / A)[..., None]  # x*dt (dA = dt*A)
     Cf, Bf = Cc.astype(jnp.float32), Bc.astype(jnp.float32)
     y_diag = jnp.einsum("cblhn,cbshn,cblsh,cbshp->cblhp", Cf, Bf, L, xdt)

@@ -87,6 +87,7 @@ def encode(
     e: jax.Array,  # (B, S, d)
     *,
     lengths: Optional[jax.Array] = None,  # (B,) valid patch counts
+    remat: bool = False,  # recompute each layer in the backward pass
 ) -> jax.Array:
     dt = e.dtype
 
@@ -100,7 +101,7 @@ def encode(
         h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
         return x + mlp(lp["ffn"], h), None
 
-    x, _ = scan_or_unroll(body, e, params["layers"])
+    x, _ = scan_or_unroll(jax.checkpoint(body) if remat else body, e, params["layers"])
     return rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
 
@@ -158,13 +159,13 @@ class VitModel:
     def embed_features(self, params, feats: jax.Array) -> jax.Array:
         return embed_features(self.cfg, params, feats)
 
-    def target_logprob_at_fn(self, params):
+    def target_logprob_at_fn(self, params, *, remat: bool = False):
         """f(embeds, aux) -> (B,) target-class log-prob; aux["pos"] is the
         last valid patch index, so lengths = pos + 1 masks bucket padding."""
 
         def f(e: jax.Array, aux: dict) -> jax.Array:
             lengths = aux["pos"] + 1
-            h = encode(self.cfg, params, e, lengths=lengths)
+            h = encode(self.cfg, params, e, lengths=lengths, remat=remat)
             lg = pool_logits(self.cfg, params, h, lengths=lengths).astype(jnp.float32)
             rows = jnp.arange(e.shape[0])
             return jax.nn.log_softmax(lg, axis=-1)[rows, aux["target"]]

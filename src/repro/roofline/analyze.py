@@ -35,51 +35,46 @@ class Hardware:
     hbm_bytes: float  # capacity per chip
 
 
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 16 GB HBM per chip. link_bw is the per-link ICI share this module charges
+# a collective (1,600 Gbit/s of chip-to-chip interconnect over 4 links).
 HW_V5E = Hardware(
     name="tpu_v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9, hbm_bytes=16e9
 )
 
-# Serving-host hardware models beyond the paper's v5e target. The numbers
-# are deliberately round generic-class figures — the autotuner
-# (repro.serve.autotune) only uses them to RANK candidate configs by their
-# roofline terms before the measured sweep, so class-accurate ratios matter,
-# absolute calibration does not.
-HW_GENERIC_GPU = Hardware(
-    name="generic_gpu", peak_flops=300e12, hbm_bw=2000e9, link_bw=300e9,
-    hbm_bytes=80e9,
-)
+# NOT a device model: the ranking model the autotuner (repro.serve.autotune)
+# uses when the CPU test backend runs it. Round numbers — only the ratio of
+# its roofline terms orders candidate configs; no CPU figure is a device
+# metric.
 HW_CPU_HOST = Hardware(
     name="cpu_host", peak_flops=2e12, hbm_bw=100e9, link_bw=25e9,
     hbm_bytes=64e9,
 )
 
-# substring match (lowercased device_kind) -> hardware model; first hit wins
-HW_BY_KIND: tuple[tuple[str, Hardware], ...] = (
-    ("tpu v5 lite", HW_V5E),
-    ("tpu", HW_V5E),
-    ("cpu", HW_CPU_HOST),
-    ("gpu", HW_GENERIC_GPU),
-    ("cuda", HW_GENERIC_GPU),
-    ("nvidia", HW_GENERIC_GPU),
-)
+# exact jax ``device_kind`` -> hardware model
+HW_BY_KIND: dict[str, Hardware] = {
+    "TPU v5 lite": HW_V5E,
+    "cpu": HW_CPU_HOST,
+}
 
 
 def hardware_for(device_kind: str) -> Hardware:
     """Resolve a ``jax.Device.device_kind`` string to a hardware model.
 
-    Unknown kinds fall back to the GPU-class model (an accelerator we have
-    no table entry for is more accelerator-like than CPU-like).
+    A kind with no table row is an error, never a guess.
 
         >>> hardware_for("cpu").name
         'cpu_host'
         >>> hardware_for("TPU v5 lite").name
         'tpu_v5e'
     """
-    kind = device_kind.lower()
-    for sub, hw in HW_BY_KIND:
-        if sub in kind:
-            return hw
-    return HW_GENERIC_GPU
+    try:
+        return HW_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for device_kind {device_kind!r}; "
+            f"known: {sorted(HW_BY_KIND)}"
+        ) from None
 
 
 def hotpath_terms(cost: dict, hw: Hardware) -> dict:

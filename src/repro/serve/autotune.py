@@ -264,7 +264,7 @@ def autotune_engine(
         if bb.bucket in seen:
             continue
         seen.add(bb.bucket)
-        args = engine._bucket_inputs(bb)
+        args = (engine.params, *engine._bucket_inputs(bb))
         sds = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
         cands = []
         for chunk in chunk_candidates(engine.m):

@@ -13,6 +13,11 @@ hot compiled program. This engine makes that true under real traffic:
     chunk)`` key is AOT-compiled (``jit(...).lower(...).compile()``) and
     cached, so steady-state traffic never recompiles — the cache and its
     hit/miss/latency stats are first-class, inspectable state;
+  * the model parameters are the first ARGUMENT of every executable, never
+    a closed-over constant: a closure would embed the weights in the
+    program (3 GB of HLO constants for a 780M-parameter model), so each
+    program function takes ``params`` and rebuilds the model function over
+    it while tracing (``_f_for``);
   * every schedule family in ``repro.core.schedule.SCHEDULES`` rides the same
     compiled path (the registry's uniform builder signature), and so does
     every attribution method in ``repro.core.methods.METHODS`` (DESIGN.md §8):
@@ -74,6 +79,7 @@ from typing import Any, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import ig, methods as methods_mod, perturb
@@ -236,6 +242,8 @@ class ExplainEngine:
             executable differentiates through the Pallas flash-attention
             custom VJP (docs/attention.md); tuned attention block sizes from
             the autotune cache rebuild the model closure per bucket.
+        remat: recompute each layer in the backward pass (bounds stage-2
+            memory for deep models at the cost of a second forward).
 
     Example (tiny CPU-reduced LM, one mixed-length round):
 
@@ -288,6 +296,7 @@ class ExplainEngine:
         hop_zero: bool = False,
         hop_zero_q: float = 0.75,
         hop_zero_min: int = 8,
+        remat: bool = False,
     ):
         # attention implementation of the SERVED model: "flash" rebuilds the
         # config with attn_impl="flash" so every executable differentiates
@@ -316,6 +325,10 @@ class ExplainEngine:
         # BENCH_hotpath trace/bytes/latency evidence, not assumed
         self.fused = fused
         self.use_kernels = use_kernels
+        # recompute each layer in the stage-2 backward instead of keeping its
+        # residuals: a deep model's folded (B·chunk) batch otherwise outgrows
+        # device memory even at chunk 1 (mamba2-780m at S=256: 18 GB)
+        self.remat = remat
         # per-(bucket, device) tuned (chunk, block_k, block_f) configs from
         # serve.autotune — loaded once at construction; a missing cache file
         # is an empty cache (every bucket falls back to the engine-wide
@@ -388,9 +401,8 @@ class ExplainEngine:
         # what warm-start persistence needs to jax.export the set
         self._export_info: dict[tuple, tuple] = {}
         self._model_fp: Optional[str] = None
-        # model fns rebuilt at tuned attention block sizes (flash only):
-        # (attn_block_q, attn_block_k) -> target_logprob_at_fn closure
-        self._attn_fns: dict[tuple[int, int], Any] = {}
+        # params replicated over the mesh, placed on first sharded call
+        self._mesh_params: Any = None
         # the compiled per-row unit: expansion stripped (row_spec) — the
         # engine samples the ensemble itself at batch-construction time
         self._explainer = Explainer(
@@ -443,49 +455,34 @@ class ExplainEngine:
                 return tuned
         return HotpathConfig(self.chunk)
 
-    def _f_for(self, cfg: HotpathConfig):
-        """The model function at one tuned config's attention block sizes.
+    def _f_for(self, cfg: HotpathConfig, params: Any):
+        """The model function over ``params`` at one tuned config's
+        attention block sizes.
 
-        Flash models bake (attn_block_q, attn_block_k) into the differentiated
-        function itself, so tuned attention blocks need a rebuilt closure —
-        cached per block pair; (0, 0) and non-flash engines reuse the
-        construction-time function.
+        Called while tracing, with ``params`` the program's first argument.
+        Flash models bake (attn_block_q, attn_block_k) into the
+        differentiated function itself; (0, 0) and non-flash engines keep
+        the config's blocks.
         """
-        blocks = (cfg.attn_block_q, cfg.attn_block_k)
-        if self.attn != "flash" or blocks == (0, 0):
-            return self._explainer.f
-        if blocks not in self._attn_fns:
+        mcfg = self.cfg
+        if self.attn == "flash" and (cfg.attn_block_q, cfg.attn_block_k) != (0, 0):
             mcfg = dataclasses.replace(
-                self.cfg, attn_block_q=blocks[0], attn_block_k=blocks[1]
+                mcfg, attn_block_q=cfg.attn_block_q, attn_block_k=cfg.attn_block_k
             )
-            self._attn_fns[blocks] = model_for(mcfg).target_logprob_at_fn(
-                self.params
-            )
-        return self._attn_fns[blocks]
+        return model_for(mcfg).target_logprob_at_fn(params, remat=self.remat)
 
-    def _explainer_at(self, cfg: HotpathConfig) -> Explainer:
-        return replace(
-            self._explainer, f=self._f_for(cfg), chunk=cfg.chunk,
-            **self._kernel_kwargs(cfg)
-        )
-
-    def _attr_fn_at(self, cfg: HotpathConfig, *, with_fx: bool = False):
+    def _attr_fn_at(self, cfg: HotpathConfig):
         """Fixed-m bucket unit at one tuned config (also the autotuner's
-        candidate-compile hook). ``with_fx`` compiles the probe-reuse variant
-        whose trailing (B,) argument donates the known endpoint f(x) — a
+        candidate-compile hook). Traced with a trailing (B,) ``f_x`` it is
+        the probe-reuse variant that donates the known endpoint f(x) — a
         DIFFERENT program (one fewer probe forward, a B-row endpoint batch),
-        so it gets its own cache-key flag."""
-        exp = self._explainer_at(cfg)
+        so it gets its own cache-key flag (``_key``'s ``with_fx``)."""
+        exp = replace(self._explainer, chunk=cfg.chunk, **self._kernel_kwargs(cfg))
 
-        if with_fx:
-
-            def attr_fx_fn(embeds, baseline, aux, mask, f_x):
-                return exp.attribute(embeds, baseline, aux, mask=mask, f_x=f_x)
-
-            return attr_fx_fn
-
-        def attr_fn(embeds, baseline, aux, mask):
-            return exp.attribute(embeds, baseline, aux, mask=mask)
+        def attr_fn(params, embeds, baseline, aux, mask, f_x=None):
+            return replace(exp, f=self._f_for(cfg, params)).attribute(
+                embeds, baseline, aux, mask=mask, f_x=f_x
+            )
 
         return attr_fn
 
@@ -526,6 +523,7 @@ class ExplainEngine:
             self.m_max, self.n_samples, self.sigma, self.sample_seed,
             self.n_masks, self.fused, self.use_kernels, self.attn,
             self._mesh_key, self.pad_id, self._autotune_cache is not None,
+            self.remat,
         ]
 
     def warm_context(self) -> str:
@@ -577,33 +575,6 @@ class ExplainEngine:
             st.result_evictions = rc.evictions
             st.result_bytes = rc.bytes
 
-    def _start_fn(self, embeds, baseline, aux, mask, f_x=None):
-        """Adaptive rung 0: fused probe + base schedule + resumable stage 2.
-
-        Returns the materialized per-example schedule too — the host needs it
-        to refine on escalation (uniform's shared (m,) schedule is broadcast
-        so survivor rows can be gathered independently). The optional
-        trailing ``f_x`` is the probe-reuse variant (only ever compiled with
-        it present or absent — the two signatures never alias, see
-        ``_key``'s with_fx flag); the returned IGState carries the endpoints
-        either way, so hop executables are IDENTICAL for both."""
-        res, state, sched = self._explainer.start(
-            embeds, baseline, aux, mask=mask, f_x=f_x
-        )
-        B = embeds.shape[0]
-        sched = Schedule(
-            jnp.broadcast_to(sched.alphas, (B, sched.alphas.shape[-1])),
-            jnp.broadcast_to(sched.weights, (B, sched.weights.shape[-1])),
-        )
-        return res, state, sched
-
-    def _hop_fn(self, embeds, baseline, aux, mask, new_nodes, state):
-        """One ladder hop: stage 2 over the refined schedule's new nodes only
-        (method-independent — the schedule arrives as runtime data)."""
-        return self._explainer.resume(
-            embeds, baseline, aux, new_nodes, state, mask=mask
-        )
-
     # -- hop-zero starting rung (DESIGN.md §7 amortization) ----------------
 
     def _explainer_for_m(self, m0: int) -> Explainer:
@@ -619,13 +590,23 @@ class ExplainEngine:
         return self._explainers_m[m0]
 
     def _start_fn_for(self, m0: int):
-        """``_start_fn`` at an elevated starting rung (same contract)."""
-        if m0 == self.m:
-            return self._start_fn
-        exp = self._explainer_for_m(m0)
+        """Adaptive rung 0 at starting rung ``m0``: fused probe + base
+        schedule + resumable stage 2.
 
-        def start_fn(embeds, baseline, aux, mask, f_x=None):
-            res, state, sched = exp.start(embeds, baseline, aux, mask=mask, f_x=f_x)
+        Returns the materialized per-example schedule too — the host needs it
+        to refine on escalation (uniform's shared (m,) schedule is broadcast
+        so survivor rows can be gathered independently). The optional
+        trailing ``f_x`` is the probe-reuse variant (only ever compiled with
+        it present or absent — the two signatures never alias, see
+        ``_key``'s with_fx flag); the returned IGState carries the endpoints
+        either way, so hop executables are IDENTICAL for both."""
+        exp = self._explainer_for_m(m0)
+        base = HotpathConfig(self.chunk)
+
+        def start_fn(params, embeds, baseline, aux, mask, f_x=None):
+            res, state, sched = replace(exp, f=self._f_for(base, params)).start(
+                embeds, baseline, aux, mask=mask, f_x=f_x
+            )
             B = embeds.shape[0]
             sched = Schedule(
                 jnp.broadcast_to(sched.alphas, (B, sched.alphas.shape[-1])),
@@ -636,12 +617,15 @@ class ExplainEngine:
         return start_fn
 
     def _hop_fn_for(self, m0: int):
-        if m0 == self.m:
-            return self._hop_fn
+        """One ladder hop: stage 2 over the refined schedule's new nodes only
+        (method-independent — the schedule arrives as runtime data)."""
         exp = self._explainer_for_m(m0)
+        base = HotpathConfig(self.chunk)
 
-        def hop_fn(embeds, baseline, aux, mask, new_nodes, state):
-            return exp.resume(embeds, baseline, aux, new_nodes, state, mask=mask)
+        def hop_fn(params, embeds, baseline, aux, mask, new_nodes, state):
+            return replace(exp, f=self._f_for(base, params)).resume(
+                embeds, baseline, aux, new_nodes, state, mask=mask
+            )
 
         return hop_fn
 
@@ -689,12 +673,14 @@ class ExplainEngine:
         shardings)`` — callers feed the pair to ``_timed_call`` so inputs are
         placed onto the mesh before the call.
 
-        ``donate`` (``donate_argnums``) marks args whose buffers the
-        executable may overwrite — hop executables donate their ``IGState``
-        so ladder escalation reuses the (B, *F) f32 accumulator in place
-        instead of copying it each rung (DESIGN.md §10; every donated arg is
-        constructed fresh per call, never read back after). Compile-time
-        roofline budgets (bytes accessed, peak bytes) are recorded on ``bs``.
+        ``donate`` (``donate_argnums``, counted over ``args``) marks args
+        whose buffers the executable may overwrite — hop executables donate
+        their ``IGState`` so ladder escalation reuses the (B, *F) f32
+        accumulator in place instead of copying it each rung (DESIGN.md §10;
+        every donated arg is constructed fresh per call, never read back
+        after). ``fn`` takes ``params`` before ``args``; the compiled program
+        does too (replicated under a mesh). Compile-time roofline budgets
+        (bytes accessed, peak bytes) are recorded on ``bs``.
         """
         hit = key in self._cache
         if hit:
@@ -708,7 +694,7 @@ class ExplainEngine:
         if self.mesh is not None and self.dp > 1:
             shardings = explain_arg_shardings(self.mesh, args, self.mesh_rules)
             if shardings is not None:
-                jit_kw["in_shardings"] = shardings
+                jit_kw["in_shardings"] = (self._replicated(), *shardings)
             else:
                 self.stats.mesh_fallbacks += 1
                 warnings.warn(
@@ -716,7 +702,10 @@ class ExplainEngine:
                     f"divide dp={self.dp}; serving replicated (key={key[:2]})",
                     stacklevel=2,
                 )
-        sds = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        sds = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (self.params, *args)
+        )
+        donate = tuple(i + 1 for i in donate)  # params ride first
         with warnings.catch_warnings():
             # CPU cannot honor donation; the aliasing request is still
             # correct on every backend and must not spam serving logs
@@ -858,7 +847,7 @@ class ExplainEngine:
         bs = self.stats.bucket(bb.bucket)
         ex = self._executable(
             self._key(bb.bucket, with_fx=with_fx), bs,
-            self._attr_fn_at(self._cfg_for(bb.bucket), with_fx=with_fx), args,
+            self._attr_fn_at(self._cfg_for(bb.bucket)), args,
         )
         res = self._timed_call(bs, ex, args)
         bs.requests += len(bb.indices)
@@ -882,7 +871,6 @@ class ExplainEngine:
         pure in (bucket shape, mask) and recomputed inside the program —
         every argument stays batch-leading for the mesh sharding rule.
         ``use_kernels`` injects the Pallas WLS solve (``kernels/lstsq``)."""
-        f = self._f_for(cfg)
         spec = self._spec
         chunk = self._fwd_chunk()
         solve = None
@@ -892,22 +880,22 @@ class ExplainEngine:
             solve = wls_solve
         if spec.accum == "lime":
 
-            def fwd_lime(embeds, baseline, aux, mask, z, zg):
+            def fwd_lime(params, embeds, baseline, aux, mask, z, zg):
                 G = zg.shape[-1]
                 gids = perturb.lime_group_ids(embeds.shape[1], G)
                 gvalid = perturb.group_real_mask(mask, gids, G)
                 return perturb.attribute_from_masks(
-                    f, embeds, baseline, aux,
+                    self._f_for(cfg, params), embeds, baseline, aux,
                     perturb.PerturbMasks(z, zg, gids), method=spec, mask=mask,
                     group_valid=gvalid, chunk=chunk, solve_fn=solve,
                 )
 
             return fwd_lime
 
-        def fwd(embeds, baseline, aux, mask, z):
+        def fwd(params, embeds, baseline, aux, mask, z):
             return perturb.attribute_from_masks(
-                f, embeds, baseline, aux, perturb.PerturbMasks(z),
-                method=spec, mask=mask, chunk=chunk,
+                self._f_for(cfg, params), embeds, baseline, aux,
+                perturb.PerturbMasks(z), method=spec, mask=mask, chunk=chunk,
             )
 
         return fwd
@@ -950,15 +938,24 @@ class ExplainEngine:
         bs.requests += len(bb.indices)
         return res
 
+    def _replicated(self) -> Any:
+        """Param shardings of a mesh executable: replicated on every device."""
+        return jax.tree.map(lambda _: NamedSharding(self.mesh, P()), self.params)
+
     def _timed_call(self, bs: BucketStats, ex: tuple, args: tuple) -> Any:
         """Run one cached ``(compiled, shardings)`` entry; sharded inputs are
         placed onto the mesh first (host→device layout is part of the serving
-        latency, so it stays inside the timer)."""
+        latency, so it stays inside the timer). The params are placed once,
+        replicated, on the first sharded call."""
         compiled, shardings = ex
         t0 = time.perf_counter()
+        params = self.params
         if shardings is not None:
             args = jax.device_put(args, shardings)
-        out = jax.block_until_ready(compiled(*args))
+            if self._mesh_params is None:
+                self._mesh_params = jax.device_put(self.params, self._replicated())
+            params = self._mesh_params
+        out = jax.block_until_ready(compiled(params, *args))
         bs.total_s += time.perf_counter() - t0
         bs.calls += 1
         return out

@@ -789,7 +789,12 @@ class MixedScheduler:
             return fn()
         try:
             out, ok = self.retry(attempt), True
-        except Exception:  # noqa: BLE001 — degradation boundary
+        except Exception as e:  # noqa: BLE001 — degradation boundary
+            warnings.warn(
+                f"MixedScheduler: {kind} work item failed after retries, "
+                f"degrading its requests: {type(e).__name__}: {e}",
+                stacklevel=2,
+            )
             out, ok = None, False
         self.monitor.observe(time.perf_counter() - t0)
         return ok, out

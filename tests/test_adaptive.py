@@ -197,10 +197,10 @@ def test_engine_full_ladder_bit_identical_lm(lm):
     start, _ = eng._executable(
         ("start", bb.bucket, "riemann", "paper", 4, 4, chunk, ()),
         eng.stats.bucket(bb.bucket),
-        eng._start_fn,
+        eng._start_fn_for(4),
         args,
     )
-    res0, state0, sched = start(*args)
+    res0, state0, sched = start(params, *args)
     fam = schedule.family("paper")
     for _ in range(2):
         sched = fam.refine(sched)
@@ -211,10 +211,10 @@ def test_engine_full_ladder_bit_identical_lm(lm):
     fixed_fn, _ = eng._executable(
         ("hop", bb.bucket, "riemann", 16, chunk, ()),
         eng.stats.hop_bucket(bb.bucket),
-        eng._hop_fn,
+        eng._hop_fn_for(4),
         fixed_args,
     )
-    fixed, _ = fixed_fn(*fixed_args)
+    fixed, _ = fixed_fn(params, *fixed_args)
     per_token = np.asarray(fixed.attributions.sum(-1))
     for row, o in enumerate(out):
         np.testing.assert_array_equal(o["raw_token_scores"], per_token[row])

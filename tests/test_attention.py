@@ -3,7 +3,7 @@
 Kernel legs mirror tests/test_kernels.py: interpret-mode Pallas vs the
 pure-jnp ref oracles on pad-exercising odd shapes, ragged kv lengths, and
 GQA head maps, under the deploy numerics (f32, bf16; f64 opts in per-test
-via jax.experimental.enable_x64). Engine legs pin the serving contracts the
+via jax.enable_x64). Engine legs pin the serving contracts the
 attention-parity CI job gates: fused and unfused adaptive escalation traces
 are EXACTLY equal on a flash LM, and a ViT engine serves patch-feature
 requests with zero steady-state recompiles.
@@ -32,7 +32,7 @@ SHAPES = [(1, 17, 4, 2, 8), (2, 33, 6, 6, 4)]
 def _dtype_ctx(dtype):
     """x64 must be enabled around f64 parity cases (and only those)."""
     if dtype == jnp.float64:
-        return jax.experimental.enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

@@ -18,10 +18,11 @@ import jax, jax.numpy as jnp
 from repro.configs import ARCHS, reduced
 from repro.configs.base import ShapeConfig
 from repro.launch.cells import build_cell, lower_cell
+from repro.launch.mesh import make_debug_mesh
 from repro.models.common import costing_mode
 from repro.roofline import cost_analysis_dict, parse_collective_bytes
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 out = {}
 cases = [
     ("llama3-8b", ShapeConfig("t", 64, 8, "train"), {"microbatches": 2}),

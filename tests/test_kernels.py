@@ -34,7 +34,7 @@ DTYPES = [jnp.float32, jnp.bfloat16, jnp.float64]
 def _dtype_ctx(dtype):
     """x64 must be enabled around f64 parity cases (and only those)."""
     if dtype == jnp.float64:
-        return jax.experimental.enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

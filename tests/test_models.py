@@ -180,6 +180,21 @@ def test_ssm_chunked_matches_small_chunk():
     )
 
 
+def test_ssm_grad_finite_at_published_chunk():
+    """At the published 256-step chunk the masked (upper) intra-chunk decay
+    exponents exceed f32's exp range; the input gradient must stay finite
+    (it is what every attribution of an SSM differentiates)."""
+    import dataclasses
+    from repro.models import ssm
+    from repro.models.common import init_params
+
+    cfg = dataclasses.replace(reduced(ARCHS["mamba2-780m"]), ssm_chunk=256)
+    p = init_params(KEY, ssm.ssm_def(cfg))
+    x = jax.random.normal(jax.random.fold_in(KEY, 6), (1, 256, cfg.d_model))
+    g = jax.grad(lambda x: ssm.ssm_forward(p, x, cfg).astype(jnp.float32).sum())(x)
+    assert bool(jnp.all(jnp.isfinite(g)))
+
+
 def test_param_count_analytic_matches_materialized():
     """ArchConfig.param_count (roofline input) == actual leaf count."""
     for arch in ("llama3-8b", "qwen3-moe-30b-a3b", "mamba2-780m", "whisper-tiny"):

@@ -8,6 +8,7 @@ from repro.configs import ARCHS, SHAPES_BY_NAME
 from repro.models.common import COSTING, costing_mode, scan_or_unroll
 from repro.roofline import (
     HW_V5E,
+    hardware_for,
     model_flops,
     parse_collective_bytes,
     roofline_report,
@@ -24,6 +25,19 @@ ENTRY main {
   ROOT %rs = f32[16,256]{1,0} reduce-scatter(%add), dimensions={0}
 }
 """
+
+
+@pytest.mark.parametrize(
+    "kind,name", [("TPU v5 lite", "tpu_v5e"), ("cpu", "cpu_host")]
+)
+def test_hardware_for_known_kinds(kind, name):
+    assert hardware_for(kind).name == name
+
+
+@pytest.mark.parametrize("kind", ["unknown", "TPU v4", "NVIDIA H100"])
+def test_hardware_for_unknown_kind_raises(kind):
+    with pytest.raises(ValueError, match="no hardware model"):
+        hardware_for(kind)
 
 
 def test_parse_collective_bytes_kinds():
