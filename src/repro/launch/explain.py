@@ -195,11 +195,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--arch/--min-seq/--max-seq)",
     )
     ap.add_argument(
-        "--remat", action="store_true",
-        help="recompute each layer in the stage-2 backward pass instead of "
-        "keeping its residuals (deep models at published widths)",
-    )
-    ap.add_argument(
         "--use-kernels", action="store_true",
         help="inject the Pallas kernel set (interpret-mode on CPU)",
     )
@@ -333,7 +328,6 @@ def make_engine(args, wl: Workload, *, schedule: str = "", mesh=None, **override
         autotune=args.autotune,
         result_cache=args.result_cache * (1 << 20),
         hop_zero=args.hop_zero,
-        remat=args.remat,
         **wl.engine_kwargs,
     )
     kw.update(overrides)

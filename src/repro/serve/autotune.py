@@ -272,8 +272,7 @@ def autotune_engine(
                 for bf in block_f_grid:
                     for abq, abk in attn_grid:
                         cfg = HotpathConfig(chunk, bk, bf, abq, abk)
-                        fn = engine._attr_fn_at(cfg)
-                        compiled = jax.jit(fn).lower(*sds).compile()
+                        compiled, _, _ = engine._compile(engine._attr_fn_at(cfg), sds, ())
                         terms = hotpath_terms(cost_analysis_dict(compiled), hw)
                         cands.append({"cfg": cfg, "compiled": compiled, **terms})
         # roofline prune: only the predicted-fastest few get measured

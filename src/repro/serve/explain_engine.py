@@ -140,6 +140,9 @@ class BucketStats:
     # observable in serving stats, not just in benchmarks
     bytes_accessed: float = 0.0
     peak_bytes: float = 0.0
+    # the last executable at this shape recomputes each layer in its
+    # backward pass: it did not fit device memory otherwise (``_compile``)
+    remat: bool = False
 
     @property
     def mean_latency_s(self) -> float:
@@ -222,6 +225,18 @@ class EngineStats:
         )
 
 
+def _peak_bytes(compiled: Any) -> Optional[float]:
+    """Argument + output + temp bytes of one compiled program
+    (``memory_analysis()``), or None where the backend reports none."""
+    try:
+        ma = compiled.memory_analysis()
+        return float(
+            ma.argument_size_in_bytes + ma.output_size_in_bytes + ma.temp_size_in_bytes
+        )
+    except Exception:  # noqa: BLE001 — backend-optional introspection
+        return None
+
+
 class ExplainEngine:
     """Bucketed, cache-compiled NUIG serving over one model + param set.
 
@@ -242,8 +257,6 @@ class ExplainEngine:
             executable differentiates through the Pallas flash-attention
             custom VJP (docs/attention.md); tuned attention block sizes from
             the autotune cache rebuild the model closure per bucket.
-        remat: recompute each layer in the backward pass (bounds stage-2
-            memory for deep models at the cost of a second forward).
 
     Example (tiny CPU-reduced LM, one mixed-length round):
 
@@ -296,7 +309,6 @@ class ExplainEngine:
         hop_zero: bool = False,
         hop_zero_q: float = 0.75,
         hop_zero_min: int = 8,
-        remat: bool = False,
     ):
         # attention implementation of the SERVED model: "flash" rebuilds the
         # config with attn_impl="flash" so every executable differentiates
@@ -325,10 +337,10 @@ class ExplainEngine:
         # BENCH_hotpath trace/bytes/latency evidence, not assumed
         self.fused = fused
         self.use_kernels = use_kernels
-        # recompute each layer in the stage-2 backward instead of keeping its
-        # residuals: a deep model's folded (B·chunk) batch otherwise outgrows
-        # device memory even at chunk 1 (mamba2-780m at S=256: 18 GB)
-        self.remat = remat
+        # read by ``_f_for`` while tracing: True while ``_compile`` re-traces
+        # a program that did not fit device memory with each layer recomputed
+        # in the backward pass
+        self._remat = False
         # per-(bucket, device) tuned (chunk, block_k, block_f) configs from
         # serve.autotune — loaded once at construction; a missing cache file
         # is an empty cache (every bucket falls back to the engine-wide
@@ -469,7 +481,7 @@ class ExplainEngine:
             mcfg = dataclasses.replace(
                 mcfg, attn_block_q=cfg.attn_block_q, attn_block_k=cfg.attn_block_k
             )
-        return model_for(mcfg).target_logprob_at_fn(params, remat=self.remat)
+        return model_for(mcfg).target_logprob_at_fn(params, remat=self._remat)
 
     def _attr_fn_at(self, cfg: HotpathConfig):
         """Fixed-m bucket unit at one tuned config (also the autotuner's
@@ -523,7 +535,6 @@ class ExplainEngine:
             self.m_max, self.n_samples, self.sigma, self.sample_seed,
             self.n_masks, self.fused, self.use_kernels, self.attn,
             self._mesh_key, self.pad_id, self._autotune_cache is not None,
-            self.remat,
         ]
 
     def warm_context(self) -> str:
@@ -706,32 +717,75 @@ class ExplainEngine:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (self.params, *args)
         )
         donate = tuple(i + 1 for i in donate)  # params ride first
-        with warnings.catch_warnings():
-            # CPU cannot honor donation; the aliasing request is still
-            # correct on every backend and must not spam serving logs
-            warnings.filterwarnings(
-                "ignore", message=".*donated buffers were not usable.*"
-            )
-            compiled = (
-                jax.jit(fn, donate_argnums=donate, **jit_kw).lower(*sds).compile()
-            )
+        compiled, fn, bs.remat = self._compile(fn, sds, donate, jit_kw)
         bs.compile_s += time.perf_counter() - t0
         bs.bytes_accessed = float(
             cost_analysis_dict(compiled).get("bytes accessed", 0.0)
         )
-        try:
-            ma = compiled.memory_analysis()
-            bs.peak_bytes = float(
-                ma.argument_size_in_bytes
-                + ma.output_size_in_bytes
-                + ma.temp_size_in_bytes
-            )
-        except Exception:  # noqa: BLE001 — backend-optional introspection
-            pass
+        peak = _peak_bytes(compiled)
+        if peak is not None:
+            bs.peak_bytes = peak
         self._cache[key] = (compiled, shardings)
         # what serve.warm_state needs to serialize this entry portably
         self._export_info[key] = (fn, sds, donate)
         return self._cache[key]
+
+    def _compile(
+        self, fn, sds: tuple, donate: tuple, jit_kw: Optional[dict] = None
+    ) -> tuple[Any, Any, bool]:
+        """Compile ``fn`` at ``sds``; a program that does not fit device
+        memory is compiled again with each layer recomputed in its backward.
+
+        Whether a stage-2 program needs remat depends on the model depth, the
+        bucket and the chunk (mamba2-780m at S=256 keeps 18 GB of layer
+        residuals for 4 rows at chunk 1; ViT-S/16 fits at chunk 8), so the
+        engine measures it: the compiler's refusal or its
+        ``memory_analysis()`` against the device's ``bytes_limit``. Backends
+        that report no limit (CPU) never remat. Returns the compiled program,
+        the function it traced (the remat variant re-traces as remat for
+        ``serve.warm_state``'s export) and whether it is the remat variant."""
+
+        def lower(f):
+            with warnings.catch_warnings():
+                # CPU cannot honor donation; the aliasing request is still
+                # correct on every backend and must not spam serving logs
+                warnings.filterwarnings(
+                    "ignore", message=".*donated buffers were not usable.*"
+                )
+                return (
+                    jax.jit(f, donate_argnums=donate, **(jit_kw or {}))
+                    .lower(*sds)
+                    .compile()
+                )
+
+        limit = self._memory_limit()
+        try:
+            compiled = lower(fn)
+            peak = _peak_bytes(compiled)
+            if limit is None or peak is None or peak <= limit:
+                return compiled, fn, False
+        except Exception as e:  # noqa: BLE001 — only an out-of-memory refusal remats
+            if limit is None or "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+
+        def remat_fn(*args):
+            prev, self._remat = self._remat, True
+            try:
+                return fn(*args)
+            finally:
+                self._remat = prev
+
+        return lower(remat_fn), remat_fn, True
+
+    def _memory_limit(self) -> Optional[int]:
+        """Bytes one executable may use on one device (``bytes_limit`` of
+        ``memory_stats()``), or None where the backend reports none."""
+        dev = (
+            self.mesh.devices.flat[0] if self.mesh is not None else jax.devices()[0]
+        )
+        stats = dev.memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        return int(limit) if limit else None
 
     def precompile_hop_zero_starts(self) -> int:
         """AOT-compile the start executables the δ-history now implies.
@@ -766,8 +820,7 @@ class ExplainEngine:
             )
             if new_key in self._cache:
                 continue
-            fn = self._start_fn_for(m0)
-            compiled = jax.jit(fn, donate_argnums=donate).lower(*sds).compile()
+            compiled, fn, _ = self._compile(self._start_fn_for(m0), sds, donate)
             self._cache[new_key] = (compiled, None)
             self._export_info[new_key] = (fn, sds, donate)
             n += 1

@@ -7,9 +7,7 @@ autotune entries and the adaptive hop-zero δ-history, with the checkpoint
 manager's atomicity discipline (``checkpoint.manager.atomic_dir``: tmp-dir
 write, per-file sha256 manifest, one ``os.replace``).
 
-Two serialized forms per executable, each best-effort at save; at restore
-the native payloads load first and the portable form fills every entry they
-do not cover:
+Two serialized forms of the executable set, tried in order at restore:
 
   * **native** (``jax.experimental.serialize_executable``): the compiled
     XLA executable itself — a true zero-compile restore (measured ~200×
@@ -19,8 +17,9 @@ do not cover:
   * **portable** (``jax.export`` StableHLO): versioned and
     device-independent, but XLA re-compiles the deserialized module at load
     (~1.4× — it saves tracing/lowering only). The fallback when the native
-    payload is stale, refuses to load, or was refused at save (XLA:CPU
-    cannot serialize some executables, so CPU restores go this way).
+    payload is stale, refuses to load, or is absent: the native form is
+    saved only when every executable serializes (XLA:CPU refuses some, so
+    CPU restores go this way).
 
 Any mismatch — corrupted file (sha256), different model fingerprint or
 engine knobs (``ExplainEngine.warm_context``), unreadable pickle — warns
@@ -43,7 +42,7 @@ import os
 import pickle
 import warnings
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import numpy as np
@@ -105,9 +104,7 @@ def _unpack_sds(packed: tuple) -> Any:
 @dataclass
 class WarmRestoreReport:
     """What ``load_warm_state`` did: ``restored`` with ``executables``
-    entries via ``"native"``, ``"export"`` or ``"native+export"`` (the
-    portable form filled what the native payloads did not cover), or cold
-    with a ``reason``."""
+    entries via ``"native"`` or ``"export"``, or cold with a ``reason``."""
 
     restored: bool
     via: str = ""
@@ -141,10 +138,11 @@ def save_warm_state(engine: Any, directory: str) -> str:
     # so restore→save carries the original blobs forward instead of dropping
     # the entry — the cycle must never shrink the warm state
     carried = getattr(engine, "_warm_saved", {"native": {}, "portable": {}})
-    native: list[dict] = []
+    # None once any executable refuses native serialization: the set then
+    # restores through the portable form alone
+    native: Optional[list[dict]] = []
     portable: list[dict] = []
     skipped = 0
-    native_errors: list[str] = []
     for key, (compiled, shardings) in engine._cache.items():
         if shardings is not None:
             skipped += 1
@@ -152,7 +150,7 @@ def save_warm_state(engine: Any, directory: str) -> str:
         info = engine._export_info.get(key)
         if info is None:
             kept = False
-            if key in carried["native"]:
+            if key in carried["native"] and native is not None:
                 native.append(carried["native"][key])
                 kept = True
             if key in carried["portable"]:
@@ -162,16 +160,22 @@ def save_warm_state(engine: Any, directory: str) -> str:
                 skipped += 1
             continue
         fn, sds, donate = info
-        try:
-            payload, in_tree, out_tree = _se.serialize(compiled)
-            native.append(
-                {"key": key, "payload": payload, "in_tree": in_tree,
-                 "out_tree": out_tree}
-            )
-        except Exception as e:  # noqa: BLE001 — native form is best-effort too
-            # e.g. XLA:CPU refuses some executables ("LessThan is not
-            # serializable"); the portable form below then covers the entry
-            native_errors.append(f"{key[:2]}: {e}")
+        if native is not None:
+            try:
+                payload, in_tree, out_tree = _se.serialize(compiled)
+                native.append(
+                    {"key": key, "payload": payload, "in_tree": in_tree,
+                     "out_tree": out_tree}
+                )
+            except Exception as e:  # noqa: BLE001 — native form is best-effort too
+                # e.g. XLA:CPU refuses some executables ("LessThan is not
+                # serializable")
+                warnings.warn(
+                    f"warm_state: no native payload ({key[:2]}: {e}); the set "
+                    "restores through the portable form",
+                    stacklevel=2,
+                )
+                native = None
         try:
             exp = jexport.export(jax.jit(fn, donate_argnums=donate))(*sds)
             portable.append(
@@ -183,12 +187,6 @@ def save_warm_state(engine: Any, directory: str) -> str:
                 "the native payload still covers this entry",
                 stacklevel=2,
             )
-    if native_errors:
-        warnings.warn(
-            f"warm_state: {len(native_errors)} executables have no native "
-            f"payload (first: {native_errors[0]}); the portable form covers them",
-            stacklevel=2,
-        )
     if skipped:
         warnings.warn(
             f"warm_state: skipped {skipped} sharded/unexportable executables "
@@ -207,6 +205,7 @@ def save_warm_state(engine: Any, directory: str) -> str:
             for (s, meth), hist in engine._delta_hist.items()
         },
     }
+    native = native or []
     with atomic_dir(directory) as tmp:
         with open(os.path.join(tmp, _NATIVE), "wb") as fh:
             pickle.dump(native, fh)
@@ -238,9 +237,9 @@ def load_warm_state(engine: Any, directory: str) -> WarmRestoreReport:
     Restore order matters: autotune entries land first (executable keys
     carry the resolved per-bucket ``HotpathConfig``, so the engine must
     resolve the same configs the save-time engine did), then the δ-history,
-    then the executables — native payloads when the manifest's jax version
-    and device kind match this process, and the portable ``jax.export`` form
-    for every entry without one.
+    then the executables — native form when the manifest's jax version and
+    device kind match this process and it holds the set, else the portable
+    ``jax.export`` form.
     EVERY validation failure falls back cold with a warning; a partial
     native restore is rolled back before trying the portable form.
     """
@@ -278,53 +277,48 @@ def load_warm_state(engine: Any, directory: str) -> WarmRestoreReport:
         manifest.get("jax_version") == jax.__version__
         and manifest.get("device_kind") == device_kind()
     )
-    native: dict = {}
     if native_ok:
+        restored: dict = {}
         try:
             with open(os.path.join(directory, _NATIVE), "rb") as fh:
                 blobs = pickle.load(fh)
             for b in blobs:
-                native[b["key"]] = (
+                restored[b["key"]] = (
                     _se.deserialize_and_load(
                         b["payload"], b["in_tree"], b["out_tree"]
                     ),
                     None,
                 )
         except Exception as e:  # noqa: BLE001 — stale native payloads degrade
-            native = {}
+            restored = {}
             warnings.warn(
                 f"warm_state: native restore failed ({e}); "
                 "trying the portable jax.export form",
                 stacklevel=2,
             )
-    # the portable form fills every entry the native payloads do not cover
-    # (all of them when the native form was refused at save or is stale)
+        if restored:
+            engine._cache.update(restored)
+            _stash_blobs(engine, directory, with_native=True)
+            return WarmRestoreReport(
+                restored=True, via="native", executables=len(restored)
+            )
     try:
         with open(os.path.join(directory, _PORTABLE), "rb") as fh:
             blobs = pickle.load(fh)
-        portable = {}
+        restored = {}
         for b in blobs:
-            if b["key"] in native:
-                continue
             exp = jexport.deserialize(b["blob"])
             sds = _unpack_sds(b["sds"])
             # donation is not re-requested here: the exported module is
             # re-compiled by XLA anyway and donation is a perf hint only
-            portable[b["key"]] = (jax.jit(exp.call).lower(*sds).compile(), None)
+            restored[b["key"]] = (jax.jit(exp.call).lower(*sds).compile(), None)
+        engine._cache.update(restored)
+        _stash_blobs(engine, directory, with_native=False)
+        return WarmRestoreReport(
+            restored=True, via="export", executables=len(restored)
+        )
     except Exception as e:  # noqa: BLE001 — never let a bad blob kill serving
-        if not native:
-            return _cold(f"portable restore failed ({e})")
-        warnings.warn(f"warm_state: portable restore failed ({e})", stacklevel=2)
-        portable = {}
-    engine._cache.update(native)
-    engine._cache.update(portable)
-    _stash_blobs(engine, directory, with_native=bool(native))
-    via = "+".join(
-        name for name, part in (("native", native), ("export", portable)) if part
-    )
-    return WarmRestoreReport(
-        restored=True, via=via or "native", executables=len(native) + len(portable)
-    )
+        return _cold(f"portable restore failed ({e})")
 
 
 def _stash_blobs(engine: Any, directory: str, *, with_native: bool) -> None:

@@ -18,3 +18,35 @@ def rng():
     its own fixed stream, so failures reproduce regardless of which other
     tests ran (no shared global numpy state)."""
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def run_child():
+    """Run a Python snippet in a fresh process with extra ``XLA_FLAGS``
+    (flags that must be set before backend init, e.g. single-threaded Eigen
+    contractions or forced host devices); ``src/`` and ``tests/`` are
+    importable there. Returns the snippet's stdout; a non-zero exit fails
+    the calling test with the tail of its stderr."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def run(script: str, xla_flags: str, timeout: int = 900) -> str:
+        env = dict(
+            os.environ,
+            PYTHONPATH=os.pathsep.join(
+                [os.path.join(root, "src"), os.path.join(root, "tests")]
+            ),
+            XLA_FLAGS=xla_flags,
+            JAX_PLATFORMS="cpu",
+        )
+        proc = subprocess.run(
+            [sys.executable, "-c", script], capture_output=True, text=True,
+            env=env, cwd=root, timeout=timeout,
+        )
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        return proc.stdout
+
+    return run

@@ -4,15 +4,10 @@ Runs in a SUBPROCESS with xla_force_host_platform_device_count=8 so the main
 test process keeps its single CPU device (the dry-run contract).
 """
 import json
-import os
-import subprocess
-import sys
 
 import pytest
 
 _SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 from repro.configs import ARCHS, reduced
@@ -51,18 +46,9 @@ print(json.dumps(out))
 
 
 @pytest.fixture(scope="module")
-def cell_results():
-    env = dict(os.environ, PYTHONPATH="src")
-    proc = subprocess.run(
-        [sys.executable, "-c", _SCRIPT],
-        capture_output=True,
-        text=True,
-        env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        timeout=1200,
-    )
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+def cell_results(run_child):
+    out = run_child(_SCRIPT, "--xla_force_host_platform_device_count=8", timeout=1200)
+    return json.loads(out.strip().splitlines()[-1])
 
 
 def test_cells_compile_on_multi_device_mesh(cell_results):

@@ -94,6 +94,33 @@ def test_masked_positions_exactly_zero(lm):
         assert np.isfinite(o["delta"])
 
 
+@pytest.mark.parametrize("adaptive", [False, True])
+def test_program_over_device_memory_compiles_with_remat(lm, monkeypatch, adaptive):
+    """A program whose memory_analysis() exceeds the device's bytes_limit is
+    compiled again with each layer recomputed in its backward pass, and
+    serves what the engine that kept its residuals serves."""
+    import dataclasses
+
+    cfg = dataclasses.replace(lm[0], compute_dtype="float32")
+    params = lm[2]
+    kw = dict(adaptive=True, m=4, m_max=8) if adaptive else {}
+    reqs = _requests(cfg, MIXED_LENS, seed=8)
+    plain = _engine(cfg, params, **kw)
+    ref = plain.explain(reqs)
+    tight = _engine(cfg, params, **kw)
+    monkeypatch.setattr(tight, "_memory_limit", lambda: 1)
+    out = tight.explain(reqs)
+    stats = [*tight.stats.buckets.values(), *tight.stats.hop_buckets.values()]
+    assert stats and all(b.remat for b in stats)
+    assert not any(b.remat for b in plain.stats.buckets.values())
+    for eng, remat in ((tight, True), (plain, False)):
+        for fn, sds, _ in eng._export_info.values():
+            assert ("remat" in str(jax.make_jaxpr(fn)(*sds))) == remat
+    for a, b in zip(out, ref):
+        np.testing.assert_allclose(a["token_scores"], b["token_scores"], atol=1e-5)
+        assert a.get("m_used") == b.get("m_used")
+
+
 # ------------------------------------------------- (b) zero new compilations
 
 

@@ -43,11 +43,11 @@ MIXED_LENS = (9, 12, 17)
 
 @pytest.fixture(scope="module")
 def lm():
-    # f32 compute: mesh padding changes a bucket's batch extent (B=2 on one
-    # device becomes B=4, one row per device), and XLA:CPU rounds a bf16
-    # forward differently at different batch extents (one bf16 ulp of a
-    # logit, ~3.5e-4 in f(x)). The §9 claim is about sharding, not bf16
-    # rounding, so it is tested where padding is exact.
+    # f32 compute: with threaded Eigen contractions (the XLA:CPU default)
+    # a bf16 forward rounds differently at a device's share of the batch
+    # than at the whole batch (one bf16 ulp of a logit, ~3.5e-4 in f(x)).
+    # The served bf16 dtype is held to the same claim in test_mesh_bf16.py,
+    # in a child process with single-threaded contractions.
     cfg = dataclasses.replace(reduced(ARCHS["llama3-8b"]), compute_dtype="float32")
     model = Model(cfg)
     return cfg, model, model.init(KEY)

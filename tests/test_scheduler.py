@@ -14,7 +14,11 @@ Covers the serving-path contracts the mixed gate
   * streamed attributions arrive position-ordered and one-per-token.
 
 Everything runs at float32 compute — the donation contract's bit-exact
-regime (docs/serving.md).
+regime (docs/serving.md). On XLA:CPU that regime also needs single-threaded
+Eigen contractions: the threaded contraction partitions its sums by output
+shape, and the decode prefill and the probe forward run different shapes,
+so their f(x) can differ in the last bits. The bit-identity test therefore
+runs in its own process with ``--xla_cpu_multi_thread_eigen=false``.
 """
 import dataclasses
 
@@ -42,8 +46,7 @@ def _prompt(n):
     return RNG.integers(1, 512, n).astype(np.int32)
 
 
-@pytest.fixture(scope="module")
-def setup():
+def _setup():
     cfg = dataclasses.replace(
         reduced(ARCHS["llama3-8b"]), compute_dtype="float32"
     )
@@ -56,6 +59,11 @@ def setup():
     return cfg, params, engine
 
 
+@pytest.fixture(scope="module")
+def setup():
+    return _setup()
+
+
 def _sched(engine, **kw):
     kw.setdefault("max_len", 16)
     kw.setdefault("decode_chunk", 2)
@@ -63,10 +71,17 @@ def _sched(engine, **kw):
     return MixedScheduler(engine, **kw)
 
 
-def test_donated_endpoint_bit_identical(setup):
+def test_donated_endpoint_bit_identical(run_child):
     """Decode-path probe == standalone ExplainEngine probe, bit for bit,
-    with identical adaptive escalation traces."""
-    _, _, engine = setup
+    with identical adaptive escalation traces (single-threaded Eigen: see
+    the module docstring)."""
+    run_child(
+        "import test_scheduler as t; t._check_donated_bit_identical(t._setup()[2])",
+        "--xla_cpu_multi_thread_eigen=false",
+    )
+
+
+def _check_donated_bit_identical(engine):
     sched = _sched(engine)
     prompts = [_prompt(6), _prompt(7)]
     tickets = [
