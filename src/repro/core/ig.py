@@ -209,7 +209,8 @@ def attribute(
         acc0 = state.acc.astype(jnp.float32)
         if state_scale != 1.0:
             acc0 = acc0 * jnp.float32(state_scale)
-    acc, _ = jax.lax.scan(step, acc0, (a_ch, w_ch))
+    with jax.named_scope("repro.stage2"):  # the name device traces show
+        acc, _ = jax.lax.scan(step, acc0, (a_ch, w_ch))
     attr = spec.finalize(acc, xp, baseline, mask)
 
     if state is not None:

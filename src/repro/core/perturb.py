@@ -270,6 +270,7 @@ def _default_solve(A, rhs, *, mask=None, ridge=0.0):
 # ---------------------------------------------------------------- attribute
 
 
+@jax.named_scope("repro.perturb")  # the name device traces show
 def attribute_from_masks(
     f: ScalarFn,
     x: jax.Array,

@@ -139,6 +139,7 @@ def probe_cost(
     return base - 1 if known_fx else base
 
 
+@jax.named_scope("repro.probe")  # the name device traces show
 def run_probe(
     kind: str,
     f: ScalarFn,

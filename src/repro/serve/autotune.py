@@ -34,7 +34,6 @@ import hashlib
 import json
 import os
 import re
-import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
@@ -43,6 +42,7 @@ import jax
 import numpy as np
 
 from repro.roofline import cost_analysis_dict, hardware_for, hotpath_terms
+from repro.serve.spans import AUTOTUNE, span
 
 DEFAULT_BLOCK_K = 8
 DEFAULT_BLOCK_F = 512
@@ -198,9 +198,9 @@ def _median_latency(call, args, rounds: int) -> float:
     call(args)  # warm (compile already done AOT; first call pays transfers)
     ts = []
     for _ in range(rounds):
-        t0 = time.perf_counter()
-        jax.block_until_ready(call(args))
-        ts.append(time.perf_counter() - t0)
+        with span(None, AUTOTUNE) as sp:
+            jax.block_until_ready(call(args))
+        ts.append(sp.seconds)
     return float(np.median(ts))
 
 

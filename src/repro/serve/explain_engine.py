@@ -70,7 +70,6 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import time
 import warnings
 import dataclasses
 from dataclasses import dataclass, field, replace
@@ -92,6 +91,17 @@ from repro.models.registry import model_for
 from repro.roofline import cost_analysis_dict
 from repro.serve.autotune import AutotuneCache, HotpathConfig, bucket_key
 from repro.serve.result_cache import ResultCache
+from repro.serve.spans import (
+    CALL,
+    COMPILE,
+    GATHER,
+    INPUTS,
+    MASKS,
+    READBACK,
+    REFINE,
+    WAIT,
+    span,
+)
 from repro.sharding import (
     DEFAULT_RULES,
     MeshRules,
@@ -201,6 +211,9 @@ class EngineStats:
     result_misses: int = 0
     result_evictions: int = 0
     result_bytes: int = 0
+    # span name -> (count, seconds), each span's wall time including the
+    # spans nested inside it (serve.spans; docs/serving.md)
+    spans: dict = field(default_factory=dict)
 
     @property
     def hit_rate(self) -> float:
@@ -699,7 +712,6 @@ class ExplainEngine:
             return self._cache[key]
         self.stats.misses += 1
         bs.compiles += 1
-        t0 = time.perf_counter()
         jit_kw = {}
         shardings = None
         if self.mesh is not None and self.dp > 1:
@@ -717,8 +729,10 @@ class ExplainEngine:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (self.params, *args)
         )
         donate = tuple(i + 1 for i in donate)  # params ride first
-        compiled, fn, bs.remat = self._compile(fn, sds, donate, jit_kw)
-        bs.compile_s += time.perf_counter() - t0
+        with span(self.stats, COMPILE) as sp:
+            compiled, fn, bs.remat = self._compile(fn, sds, donate, jit_kw, sp=sp)
+            sp.tag(remat=bs.remat)
+        bs.compile_s += sp.seconds
         bs.bytes_accessed = float(
             cost_analysis_dict(compiled).get("bytes accessed", 0.0)
         )
@@ -731,7 +745,8 @@ class ExplainEngine:
         return self._cache[key]
 
     def _compile(
-        self, fn, sds: tuple, donate: tuple, jit_kw: Optional[dict] = None
+        self, fn, sds: tuple, donate: tuple, jit_kw: Optional[dict] = None,
+        *, sp: Optional[span] = None,
     ) -> tuple[Any, Any, bool]:
         """Compile ``fn`` at ``sds``; a program that does not fit device
         memory is compiled again with each layer recomputed in its backward.
@@ -743,7 +758,8 @@ class ExplainEngine:
         ``memory_analysis()`` against the device's ``bytes_limit``. Backends
         that report no limit (CPU) never remat. Returns the compiled program,
         the function it traced (the remat variant re-traces as remat for
-        ``serve.warm_state``'s export) and whether it is the remat variant."""
+        ``serve.warm_state``'s export) and whether it is the remat variant.
+        ``sp``, the caller's compile span, is tagged when the compiler refuses."""
 
         def lower(f):
             with warnings.catch_warnings():
@@ -767,6 +783,8 @@ class ExplainEngine:
         except Exception as e:  # noqa: BLE001 — only an out-of-memory refusal remats
             if limit is None or "RESOURCE_EXHAUSTED" not in str(e):
                 raise
+            if sp is not None:
+                sp.tag(refused=True)
 
         def remat_fn(*args):
             prev, self._remat = self._remat, True
@@ -829,70 +847,71 @@ class ExplainEngine:
     # -- serving -----------------------------------------------------------
 
     def _bucket_inputs(self, bb: BucketBatch) -> tuple:
-        tokens = jnp.asarray(bb.tokens)
-        aux = {
-            "target": jnp.asarray(bb.targets, jnp.int32),
-            "pos": jnp.asarray(bb.lens - 1, jnp.int32),
-        }
-        mask = jnp.asarray(bb.mask)
-        if bb.features is not None:
-            # feature-space requests (ViT patches): the IG path interpolates
-            # embedded features toward the embedded BLACK image (an affine
-            # patch projection maps the paper's pixel-space straight line to
-            # exactly this embedding-space line; the bias+posemb offset is
-            # shared, so it is off-path-direction and the baseline gradient
-            # is non-degenerate — unlike a zero embedding)
-            feats = jnp.asarray(bb.features)
-            embeds = self.model.embed_features(self.params, feats)
-            baseline = self.model.embed_features(
-                self.params, jnp.zeros_like(feats)
-            )
-        else:
-            embeds = self.model.embed_inputs(self.params, {"tokens": tokens})
-            # PAD-token embedding, not zeros: RMSNorm backbones are scale-
-            # invariant through their first norm, so a ray through the origin
-            # has (near-)zero gradient a.e. and completeness can never
-            # converge.
-            baseline = pad_embedding(
-                self.params["embed"]["embedding"], embeds, pad_id=self.pad_id
-            )
-        if self._spec.expand is not None:
-            # path-ensemble perturbation in embedding space: rows are already
-            # replicated requests (see explain()), so each row draws its own
-            # iid sample here — OUTSIDE the compiled program, which is what
-            # keeps ensemble methods on the shared riemann executables. Each
-            # row's key is a pure function of ITS OWN (expanded) request
-            # index, NOT a call counter and NOT the batch shape: replayed
-            # traffic must draw the same ensemble so its escalation path —
-            # and therefore the set of hop shapes it touches — replays
-            # exactly (zero recompiles), and a mesh-padded bucket (B rounded
-            # up to the dp multiple, DESIGN.md §9) must draw the same
-            # per-row ensemble as the single-device bucket (sharded parity).
-            # Batch-pad rows duplicate the last real request's index, so
-            # their (discarded) noise duplicates too.
-            base = jax.random.fold_in(
-                jax.random.PRNGKey(self.sample_seed), bb.bucket[1]
-            )
-            padded = list(bb.indices)
-            padded += [padded[-1]] * (bb.bucket[0] - len(padded))
-            # one vmapped draw, not a per-row loop: same per-row streams
-            # (each row's draw depends only on its own key), O(1) dispatches
-            keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
-                jnp.asarray(padded, jnp.uint32)
-            )
-            e2, b2 = jax.vmap(
-                lambda e, b, k: self._spec.expand(
-                    e[None], b[None], k, 1, self.sigma
+        with span(self.stats, INPUTS, bucket=bb.bucket):
+            tokens = jnp.asarray(bb.tokens)
+            aux = {
+                "target": jnp.asarray(bb.targets, jnp.int32),
+                "pos": jnp.asarray(bb.lens - 1, jnp.int32),
+            }
+            mask = jnp.asarray(bb.mask)
+            if bb.features is not None:
+                # feature-space requests (ViT patches): the IG path interpolates
+                # embedded features toward the embedded BLACK image (an affine
+                # patch projection maps the paper's pixel-space straight line to
+                # exactly this embedding-space line; the bias+posemb offset is
+                # shared, so it is off-path-direction and the baseline gradient
+                # is non-degenerate — unlike a zero embedding)
+                feats = jnp.asarray(bb.features)
+                embeds = self.model.embed_features(self.params, feats)
+                baseline = self.model.embed_features(
+                    self.params, jnp.zeros_like(feats)
                 )
-            )(embeds, baseline, keys)
-            embeds, baseline = e2[:, 0], b2[:, 0]
-        if bb.f_x is not None:
-            # probe-reuse bucket (docs/serving.md): the donated endpoint rides
-            # as a trailing (B,) f32 argument. plan_buckets never mixes
-            # known-fx and self-probing requests in one bucket, and explain()
-            # strips f_x for ensemble methods before planning.
-            return embeds, baseline, aux, mask, jnp.asarray(bb.f_x, jnp.float32)
-        return embeds, baseline, aux, mask
+            else:
+                embeds = self.model.embed_inputs(self.params, {"tokens": tokens})
+                # PAD-token embedding, not zeros: RMSNorm backbones are scale-
+                # invariant through their first norm, so a ray through the origin
+                # has (near-)zero gradient a.e. and completeness can never
+                # converge.
+                baseline = pad_embedding(
+                    self.params["embed"]["embedding"], embeds, pad_id=self.pad_id
+                )
+            if self._spec.expand is not None:
+                # path-ensemble perturbation in embedding space: rows are already
+                # replicated requests (see explain()), so each row draws its own
+                # iid sample here — OUTSIDE the compiled program, which is what
+                # keeps ensemble methods on the shared riemann executables. Each
+                # row's key is a pure function of ITS OWN (expanded) request
+                # index, NOT a call counter and NOT the batch shape: replayed
+                # traffic must draw the same ensemble so its escalation path —
+                # and therefore the set of hop shapes it touches — replays
+                # exactly (zero recompiles), and a mesh-padded bucket (B rounded
+                # up to the dp multiple, DESIGN.md §9) must draw the same
+                # per-row ensemble as the single-device bucket (sharded parity).
+                # Batch-pad rows duplicate the last real request's index, so
+                # their (discarded) noise duplicates too.
+                base = jax.random.fold_in(
+                    jax.random.PRNGKey(self.sample_seed), bb.bucket[1]
+                )
+                padded = list(bb.indices)
+                padded += [padded[-1]] * (bb.bucket[0] - len(padded))
+                # one vmapped draw, not a per-row loop: same per-row streams
+                # (each row's draw depends only on its own key), O(1) dispatches
+                keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
+                    jnp.asarray(padded, jnp.uint32)
+                )
+                e2, b2 = jax.vmap(
+                    lambda e, b, k: self._spec.expand(
+                        e[None], b[None], k, 1, self.sigma
+                    )
+                )(embeds, baseline, keys)
+                embeds, baseline = e2[:, 0], b2[:, 0]
+            if bb.f_x is not None:
+                # probe-reuse bucket (docs/serving.md): the donated endpoint rides
+                # as a trailing (B,) f32 argument. plan_buckets never mixes
+                # known-fx and self-probing requests in one bucket, and explain()
+                # strips f_x for ensemble methods before planning.
+                return embeds, baseline, aux, mask, jnp.asarray(bb.f_x, jnp.float32)
+            return embeds, baseline, aux, mask
 
     def _run_bucket(self, bb: BucketBatch) -> Any:
         args = self._bucket_inputs(bb)
@@ -967,10 +986,11 @@ class ExplainEngine:
         S = bb.bucket[1]
         padded = list(bb.indices)
         padded += [padded[-1]] * (bb.bucket[0] - len(padded))
-        keys = jax.vmap(
-            lambda i: perturb.request_key(self.sample_seed, S, i)
-        )(jnp.asarray(padded, jnp.uint32))
-        pm = perturb.draw_masks(self._spec.name, keys, S, self.n_masks)
+        with span(self.stats, MASKS, bucket=bb.bucket):
+            keys = jax.vmap(
+                lambda i: perturb.request_key(self.sample_seed, S, i)
+            )(jnp.asarray(padded, jnp.uint32))
+            pm = perturb.draw_masks(self._spec.name, keys, S, self.n_masks)
         if pm.groups is not None:
             return embeds, baseline, aux, mask, pm.z, pm.groups
         return embeds, baseline, aux, mask, pm.z
@@ -998,18 +1018,21 @@ class ExplainEngine:
     def _timed_call(self, bs: BucketStats, ex: tuple, args: tuple) -> Any:
         """Run one cached ``(compiled, shardings)`` entry; sharded inputs are
         placed onto the mesh first (host→device layout is part of the serving
-        latency, so it stays inside the timer). The params are placed once,
-        replicated, on the first sharded call."""
+        latency, so it stays inside the timed span). The params are placed
+        once, replicated, on the first sharded call. The ``wait`` span inside
+        the ``call`` span splits dispatch from the device's work."""
         compiled, shardings = ex
-        t0 = time.perf_counter()
-        params = self.params
-        if shardings is not None:
-            args = jax.device_put(args, shardings)
-            if self._mesh_params is None:
-                self._mesh_params = jax.device_put(self.params, self._replicated())
-            params = self._mesh_params
-        out = jax.block_until_ready(compiled(params, *args))
-        bs.total_s += time.perf_counter() - t0
+        with span(self.stats, CALL, bucket=args[0].shape[:2]) as sp:
+            params = self.params
+            if shardings is not None:
+                args = jax.device_put(args, shardings)
+                if self._mesh_params is None:
+                    self._mesh_params = jax.device_put(self.params, self._replicated())
+                params = self._mesh_params
+            out = compiled(params, *args)
+            with span(self.stats, WAIT):
+                out = jax.block_until_ready(out)
+        bs.total_s += sp.seconds
         bs.calls += 1
         return out
 
@@ -1246,24 +1269,25 @@ class AdaptiveBucketRun:
             known_fx=with_fx,
         )
 
-        embeds, baseline, aux, mask = args[:4]
-        self.embeds = np.asarray(embeds)
-        self.baseline = np.asarray(baseline)
-        self.aux = {k: np.asarray(v) for k, v in aux.items()}
-        self.mask = np.asarray(mask)
-        self.delta = np.asarray(res.delta).copy()
-        self.f_x = np.asarray(res.f_x)
-        self.f_b = np.asarray(res.f_baseline)
-        self.threshold = eng.tol * np.abs(self.f_x - self.f_b)
-        self.per_token = np.asarray(res.attributions.sum(-1)).copy()  # (B, S)
-        self.m_used = np.full((bb.bucket[0],), self.m0, np.int64)
-        self.hops = np.zeros((bb.bucket[0],), np.int64)
+        with span(eng.stats, READBACK):
+            embeds, baseline, aux, mask = args[:4]
+            self.embeds = np.asarray(embeds)
+            self.baseline = np.asarray(baseline)
+            self.aux = {k: np.asarray(v) for k, v in aux.items()}
+            self.mask = np.asarray(mask)
+            self.delta = np.asarray(res.delta).copy()
+            self.f_x = np.asarray(res.f_x)
+            self.f_b = np.asarray(res.f_baseline)
+            self.threshold = eng.tol * np.abs(self.f_x - self.f_b)
+            self.per_token = np.asarray(res.attributions.sum(-1)).copy()  # (B, S)
+            self.m_used = np.full((bb.bucket[0],), self.m0, np.int64)
+            self.hops = np.zeros((bb.bucket[0],), np.int64)
 
-        # survivors: real rows whose δ still exceeds tol·|f_x − f_b|
-        self.act = [r for r in range(n_real) if self.delta[r] > self.threshold[r]]
-        self.a_act = np.asarray(sched.alphas)[self.act]
-        self.w_act = np.asarray(sched.weights)[self.act]
-        self.acc_act = np.asarray(state.acc)[self.act]
+            # survivors: real rows whose δ still exceeds tol·|f_x − f_b|
+            self.act = [r for r in range(n_real) if self.delta[r] > self.threshold[r]]
+            self.a_act = np.asarray(sched.alphas)[self.act]
+            self.w_act = np.asarray(sched.weights)[self.act]
+            self.acc_act = np.asarray(state.acc)[self.act]
 
     def hop(self) -> bool:
         """Run ONE escalation rung over the survivors; returns ``active``.
@@ -1281,23 +1305,26 @@ class AdaptiveBucketRun:
         rung = eng.m_ladder[self._rung_i]
         self._rung_i += 1
         n_new = rung // 2
-        refined = family(eng.schedule).refine(
-            Schedule(jnp.asarray(self.a_act), jnp.asarray(self.w_act))
-        )
-        ra, rw = np.asarray(refined.alphas), np.asarray(refined.weights)
-        rows, B2 = pad_rows(act, eng.batch_buckets, multiple=eng.dp)
-        # schedule/state slot per padded row: pad_rows keeps act as a
-        # prefix and repeats the last real row into the pad slots
-        pad_sel = list(range(len(act))) + [len(act) - 1] * (B2 - len(act))
-        hop_bucket = (B2, S)
-        hop_args = (
-            self.embeds[rows],
-            self.baseline[rows],
-            {k: v[rows] for k, v in self.aux.items()},
-            self.mask[rows],
-            Schedule(ra[pad_sel, n_new:], rw[pad_sel, n_new:]),
-            ig.IGState(self.acc_act[pad_sel], self.f_x[rows], self.f_b[rows]),
-        )
+        with span(eng.stats, REFINE, rung=rung):
+            refined = family(eng.schedule).refine(
+                Schedule(jnp.asarray(self.a_act), jnp.asarray(self.w_act))
+            )
+            ra, rw = np.asarray(refined.alphas), np.asarray(refined.weights)
+        with span(eng.stats, GATHER, rows=len(act)) as sp:
+            rows, B2 = pad_rows(act, eng.batch_buckets, multiple=eng.dp)
+            sp.tag(B=B2)
+            # schedule/state slot per padded row: pad_rows keeps act as a
+            # prefix and repeats the last real row into the pad slots
+            pad_sel = list(range(len(act))) + [len(act) - 1] * (B2 - len(act))
+            hop_bucket = (B2, S)
+            hop_args = (
+                self.embeds[rows],
+                self.baseline[rows],
+                {k: v[rows] for k, v in self.aux.items()},
+                self.mask[rows],
+                Schedule(ra[pad_sel, n_new:], rw[pad_sel, n_new:]),
+                ig.IGState(self.acc_act[pad_sel], self.f_x[rows], self.f_b[rows]),
+            )
         hop_key = ("hop", hop_bucket, eng._spec.accum, n_new, self.chunk,
                    eng.fused, eng.use_kernels, eng.attn, eng._mesh_key)
         hbs = eng.stats.hop_bucket(hop_bucket)
@@ -1314,20 +1341,21 @@ class AdaptiveBucketRun:
         ast.launched_steps += B2 * n_new
         ast.total_steps += len(act) * n_new
 
-        d2 = np.asarray(res2.delta)
-        pt2 = np.asarray(res2.attributions.sum(-1))
-        acc2 = np.asarray(st2.acc)
-        keep = []
-        for slot, r in enumerate(act):  # real survivors occupy slots [0, len(act))
-            self.delta[r] = d2[slot]
-            self.per_token[r] = pt2[slot]
-            self.m_used[r] = rung
-            self.hops[r] += 1
-            if d2[slot] > self.threshold[r]:
-                keep.append(slot)
-        self.act = [act[s] for s in keep]
-        self.a_act, self.w_act = ra[keep], rw[keep]
-        self.acc_act = acc2[keep]
+        with span(eng.stats, READBACK):
+            d2 = np.asarray(res2.delta)
+            pt2 = np.asarray(res2.attributions.sum(-1))
+            acc2 = np.asarray(st2.acc)
+            keep = []
+            for slot, r in enumerate(act):  # real survivors occupy slots [0, len(act))
+                self.delta[r] = d2[slot]
+                self.per_token[r] = pt2[slot]
+                self.m_used[r] = rung
+                self.hops[r] += 1
+                if d2[slot] > self.threshold[r]:
+                    keep.append(slot)
+            self.act = [act[s] for s in keep]
+            self.a_act, self.w_act = ra[keep], rw[keep]
+            self.acc_act = acc2[keep]
         return self.active
 
     def degrade(self) -> int:

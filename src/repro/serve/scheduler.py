@@ -63,6 +63,7 @@ from repro.serve.explain_engine import (
     ExplainEngine,
     ExplainRequest,
 )
+from repro.serve.spans import COMPILE, DELIVER, FLUSH, ITEM, STEP, SUBMIT, span
 
 # -- request classes ---------------------------------------------------------
 
@@ -126,6 +127,10 @@ class Ticket:
     rejected_rate. ``tokens`` accumulates emitted ids; ``attributions``
     accumulates per-position explain result dicts (each tagged ``pos`` /
     ``token``) in emission order; explain-only tickets get ``result``.
+    ``dispatched_s`` is when the first work item carrying the ticket started
+    (None until then, and for a ticket refused at the door; a result-cache
+    hit is dispatched as it is submitted), so ``dispatched_s - submitted_s``
+    is the ticket's wait in the queue.
     """
 
     id: int
@@ -138,6 +143,7 @@ class Ticket:
     result: Optional[dict] = None
     degraded: bool = False
     submitted_s: float = 0.0
+    dispatched_s: Optional[float] = None
     finished_s: float = 0.0
     # internal completion tracking
     _decode_done: bool = False
@@ -289,64 +295,66 @@ class MixedScheduler:
         poisoned request must not reach, and kill, the dispatch loop) are
         reported on the ticket, never raised.
         """
-        is_gen = isinstance(req, GenerateRequest)
-        t = Ticket(
-            id=self._next_id,
-            kind="generate" if is_gen else "explain",
-            tenant=req.tenant if is_gen else tenant,
-            slo=(slo or req.slo) if is_gen else (
-                slo or (BATCH if self.engine._spec.forward_only else EXPLAIN)
-            ),
-            submitted_s=self.time_fn(),
-        )
-        self._next_id += 1
-        self.tickets.append(t)
-        if not is_gen:
-            hit = self._cached_result(req)
-            if hit is not None:
-                # content-addressed replay (serve.result_cache): a hit is
-                # admitted BEFORE backpressure and rate checks — it costs no
-                # queue slot, no tenant budget, and never preempts decode,
-                # so cached traffic cannot push fresh traffic into rejection
-                t.result = hit
-                t._decode_done = True
-                t._pending_explains = 0
-                self._finish(t)
+        with span(self.engine.stats, SUBMIT):
+            is_gen = isinstance(req, GenerateRequest)
+            t = Ticket(
+                id=self._next_id,
+                kind="generate" if is_gen else "explain",
+                tenant=req.tenant if is_gen else tenant,
+                slo=(slo or req.slo) if is_gen else (
+                    slo or (BATCH if self.engine._spec.forward_only else EXPLAIN)
+                ),
+                submitted_s=self.time_fn(),
+            )
+            self._next_id += 1
+            self.tickets.append(t)
+            if not is_gen:
+                hit = self._cached_result(req)
+                if hit is not None:
+                    # content-addressed replay (serve.result_cache): a hit is
+                    # admitted BEFORE backpressure and rate checks — it costs no
+                    # queue slot, no tenant budget, and never preempts decode,
+                    # so cached traffic cannot push fresh traffic into rejection
+                    t.result = hit
+                    t.dispatched_s = t.submitted_s
+                    t._decode_done = True
+                    t._pending_explains = 0
+                    self._finish(t)
+                    return t
+            if self.queue_depth >= self.max_queue:
+                t.status = "rejected_backpressure"
+                self.rejected_backpressure += 1
                 return t
-        if self.queue_depth >= self.max_queue:
-            t.status = "rejected_backpressure"
-            self.rejected_backpressure += 1
-            return t
-        bucket = self._buckets.get(t.tenant)
-        if bucket is not None and not bucket.try_take():
-            t.status = "rejected_rate"
-            self.rejected_rate += 1
-            return t
-        try:  # poisoned-size admission check: degrade, don't explode later
-            bucket_for(len(req.tokens), self.engine.seq_buckets)
-            if is_gen and len(req.tokens) + req.num_tokens > self.max_len:
-                raise ValueError("prompt + generation exceeds KV capacity")
-        except ValueError:
-            self._degrade_ticket(t, reason="admission")
-            return t
-        if is_gen:
-            if req.num_tokens <= 0:
+            bucket = self._buckets.get(t.tenant)
+            if bucket is not None and not bucket.try_take():
+                t.status = "rejected_rate"
+                self.rejected_rate += 1
+                return t
+            try:  # poisoned-size admission check: degrade, don't explode later
+                bucket_for(len(req.tokens), self.engine.seq_buckets)
+                if is_gen and len(req.tokens) + req.num_tokens > self.max_len:
+                    raise ValueError("prompt + generation exceeds KV capacity")
+            except ValueError:
+                self._degrade_ticket(t, reason="admission")
+                return t
+            if is_gen:
+                if req.num_tokens <= 0:
+                    t.tokens = np.zeros((0,), np.int32)
+                    self._finish(t)
+                    return t
                 t.tokens = np.zeros((0,), np.int32)
-                self._finish(t)
-                return t
-            t.tokens = np.zeros((0,), np.int32)
-            self._pending_gen.append((t, req))
-            if not self._gen_flush_queued:
-                self._gen_flush_queued = True
-                self._push(t.slo.priority, "gen_flush", None)
-        else:
-            t._pending_explains = 1
-            t._decode_done = True
-            self._pending_exp.append((t, -1, None, req))
-            if not self._exp_flush_queued:
-                self._exp_flush_queued = True
-                self._push(_PRIO_EXPLAIN_WORK, "exp_flush", None)
-        return t
+                self._pending_gen.append((t, req))
+                if not self._gen_flush_queued:
+                    self._gen_flush_queued = True
+                    self._push(t.slo.priority, "gen_flush", None)
+            else:
+                t._pending_explains = 1
+                t._decode_done = True
+                self._pending_exp.append((t, -1, None, req))
+                if not self._exp_flush_queued:
+                    self._exp_flush_queued = True
+                    self._push(_PRIO_EXPLAIN_WORK, "exp_flush", None)
+            return t
 
     # -- dispatch loop -------------------------------------------------------
 
@@ -378,7 +386,8 @@ class MixedScheduler:
             "exp_start": self._do_exp_start,
             "hop": self._do_hop,
         }[kind]
-        handler(payload)
+        with span(self.engine.stats, STEP, kind=kind):
+            handler(payload)
         self.engine.stats.queue_depth = self.queue_depth
         return True
 
@@ -417,36 +426,37 @@ class MixedScheduler:
     def _do_exp_flush(self, _payload) -> None:
         self._exp_flush_queued = False
         pending, self._pending_exp = self._pending_exp, []
-        forward_only = self.engine._spec.forward_only
-        if forward_only:
-            # forward-only buckets self-probe both endpoints inside ONE
-            # executable class — a decode-donated f_x would fork the compile
-            # key for nothing (there is no gradient pass to save)
-            pending = [
-                (t, pos, tok, replace(r, f_x=None) if r.f_x is not None else r)
-                for (t, pos, tok, r) in pending
-            ]
-        reqs = [p[3] for p in pending]
-        plan = plan_buckets(
-            reqs,
-            seq_buckets=self.engine.seq_buckets,
-            batch_buckets=self.engine.batch_buckets,
-            max_batch=self.engine.max_batch,
-            pad_id=self.engine.pad_id,
-            batch_multiple=self.engine.dp,
-        )
-        for bb in plan:
-            reqmap = [pending[i] for i in bb.indices]
+        with span(self.engine.stats, FLUSH, rows=len(pending)):
+            forward_only = self.engine._spec.forward_only
             if forward_only:
-                # perturbation mask batches are preemptible BATCH-class
-                # work: queued at the hop rung so interactive decode always
-                # dispatches first (and counts the deferral, step())
-                self._push(_PRIO_HOP, "exp_fwd", (bb, reqmap))
-            elif self.engine.adaptive:
-                run = AdaptiveBucketRun(self.engine, bb)
-                self._push(_PRIO_EXPLAIN_WORK, "exp_start", (run, reqmap))
-            else:
-                self._push(_PRIO_EXPLAIN_WORK, "exp_fixed", (bb, reqmap))
+                # forward-only buckets self-probe both endpoints inside ONE
+                # executable class — a decode-donated f_x would fork the compile
+                # key for nothing (there is no gradient pass to save)
+                pending = [
+                    (t, pos, tok, replace(r, f_x=None) if r.f_x is not None else r)
+                    for (t, pos, tok, r) in pending
+                ]
+            reqs = [p[3] for p in pending]
+            plan = plan_buckets(
+                reqs,
+                seq_buckets=self.engine.seq_buckets,
+                batch_buckets=self.engine.batch_buckets,
+                max_batch=self.engine.max_batch,
+                pad_id=self.engine.pad_id,
+                batch_multiple=self.engine.dp,
+            )
+            for bb in plan:
+                reqmap = [pending[i] for i in bb.indices]
+                if forward_only:
+                    # perturbation mask batches are preemptible BATCH-class
+                    # work: queued at the hop rung so interactive decode always
+                    # dispatches first (and counts the deferral, step())
+                    self._push(_PRIO_HOP, "exp_fwd", (bb, reqmap))
+                elif self.engine.adaptive:
+                    run = AdaptiveBucketRun(self.engine, bb)
+                    self._push(_PRIO_EXPLAIN_WORK, "exp_start", (run, reqmap))
+                else:
+                    self._push(_PRIO_EXPLAIN_WORK, "exp_fixed", (bb, reqmap))
 
     # -- decode items --------------------------------------------------------
 
@@ -460,13 +470,12 @@ class MixedScheduler:
         self.engine.stats.misses += 1
         bs = self.decode_stats.setdefault(key, BucketStats())
         bs.compiles += 1
-        t0 = time.perf_counter()
         sds = [
             a if i in static
             else jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
             for i, a in enumerate(args)
         ]
-        with warnings.catch_warnings():
+        with span(self.engine.stats, COMPILE) as sp, warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message=".*donated buffers were not usable.*"
             )
@@ -475,7 +484,7 @@ class MixedScheduler:
                 .lower(*sds)
                 .compile()
             )
-        bs.compile_s += time.perf_counter() - t0
+        bs.compile_s += sp.seconds
         self._exec_cache[key] = ent
         return ent
 
@@ -485,6 +494,7 @@ class MixedScheduler:
         ex = self._aot(
             ("dprefill", B, S), self._prefill_fn, (self.engine.params, batch)
         )
+        self._dispatched(grp.tickets)
         ok, out = self._run_item("prefill", grp, lambda: ex(self.engine.params, batch))
         if not ok:
             for t in grp.tickets:
@@ -636,6 +646,7 @@ class MixedScheduler:
 
     def _do_exp_fixed(self, payload) -> None:
         bb, reqmap = payload
+        self._dispatched(t for t, *_ in reqmap)
         ok, res = self._run_item(
             "exp_fixed", bb, lambda: self.engine._run_bucket(bb)
         )
@@ -644,22 +655,13 @@ class MixedScheduler:
             for (t, pos, token, req) in reqmap:
                 self._deliver_degraded(t, pos, token, n_tokens=len(req.tokens))
             return
-        per_token = np.asarray(res.attributions.sum(-1))
-        for row, (t, pos, token, req) in enumerate(reqmap):
-            r = {
-                "token_scores": per_token[row, : bb.lens[row]],
-                "delta": float(res.delta[row]),
-                "f_x": float(res.f_x[row]),
-                "f_baseline": float(res.f_baseline[row]),
-                "bucket": bb.bucket,
-                "degraded": False,
-                "raw_token_scores": per_token[row],
-            }
-            self._cache_result(req, r)
-            self._deliver(t, pos, token, r)
+        with span(self.engine.stats, DELIVER, rows=len(reqmap)):
+            per_token = np.asarray(res.attributions.sum(-1))
+            self._deliver_rows(bb, reqmap, res, per_token)
 
     def _do_exp_fwd(self, payload) -> None:
         bb, reqmap = payload
+        self._dispatched(t for t, *_ in reqmap)
         ok, res = self._run_item(
             "exp_fwd", bb, lambda: self.engine._run_bucket_fwd(bb)
         )
@@ -668,8 +670,13 @@ class MixedScheduler:
             for (t, pos, token, req) in reqmap:
                 self._deliver_degraded(t, pos, token, n_tokens=len(req.tokens))
             return
-        # perturbation scores are per POSITION already — no feature axis
-        per_token = np.asarray(res.attributions)
+        with span(self.engine.stats, DELIVER, rows=len(reqmap)):
+            # perturbation scores are per POSITION already — no feature axis
+            per_token = np.asarray(res.attributions)
+            self._deliver_rows(bb, reqmap, res, per_token)
+
+    def _deliver_rows(self, bb, reqmap, res, per_token: np.ndarray) -> None:
+        """Convert, cache and deliver one fixed-budget bucket's rows."""
         for row, (t, pos, token, req) in enumerate(reqmap):
             r = {
                 "token_scores": per_token[row, : bb.lens[row]],
@@ -685,6 +692,7 @@ class MixedScheduler:
 
     def _do_exp_start(self, payload) -> None:
         run, reqmap = payload
+        self._dispatched(t for t, *_ in reqmap)
         ok, _ = self._run_item("exp_start", run, run.start)
         if not ok:
             # rung 0 never ran: there is no partial result to fall back to
@@ -709,11 +717,19 @@ class MixedScheduler:
             self._deliver_run(run, reqmap)
 
     def _deliver_run(self, run: AdaptiveBucketRun, reqmap) -> None:
-        # results arrive in bb.indices order — exactly reqmap's order
-        for r, (t, pos, token, req) in zip(run.results(), reqmap):
-            r.pop("request", None)
-            self._cache_result(req, r)
-            self._deliver(t, pos, token, r)
+        with span(self.engine.stats, DELIVER, rows=len(reqmap)):
+            # results arrive in bb.indices order — exactly reqmap's order
+            for r, (t, pos, token, req) in zip(run.results(), reqmap):
+                r.pop("request", None)
+                self._cache_result(req, r)
+                self._deliver(t, pos, token, r)
+
+    def _dispatched(self, tickets) -> None:
+        """Stamp the tickets a starting work item carries, the first time."""
+        now = self.time_fn()
+        for t in tickets:
+            if t.dispatched_s is None:
+                t.dispatched_s = now
 
     # -- completion / degradation -------------------------------------------
 
@@ -782,21 +798,21 @@ class MixedScheduler:
         """One retried, straggler-observed work item. Returns (ok, result);
         ``ok=False`` means the retry policy exhausted — the caller degrades
         the affected requests and the loop keeps serving."""
-        t0 = time.perf_counter()
         def attempt():
             if self.fault_hook is not None:
                 self.fault_hook(kind, payload)
             return fn()
-        try:
-            out, ok = self.retry(attempt), True
-        except Exception as e:  # noqa: BLE001 — degradation boundary
-            warnings.warn(
-                f"MixedScheduler: {kind} work item failed after retries, "
-                f"degrading its requests: {type(e).__name__}: {e}",
-                stacklevel=2,
-            )
-            out, ok = None, False
-        self.monitor.observe(time.perf_counter() - t0)
+        with span(self.engine.stats, ITEM, kind=kind) as sp:
+            try:
+                out, ok = self.retry(attempt), True
+            except Exception as e:  # noqa: BLE001 — degradation boundary
+                warnings.warn(
+                    f"MixedScheduler: {kind} work item failed after retries, "
+                    f"degrading its requests: {type(e).__name__}: {e}",
+                    stacklevel=2,
+                )
+                out, ok = None, False
+        self.monitor.observe(sp.seconds)
         return ok, out
 
     # -- reporting -----------------------------------------------------------
