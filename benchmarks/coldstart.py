@@ -192,19 +192,23 @@ def run(*, arch: str = "llama3-8b", smoke: bool = False, seed: int = 0) -> dict:
         "cold_to_first_s": cold_to_first_s,
         "warm_to_first_s": warm_to_first_s,
         "speedup": speedup, "warm_compiles": warm.stats.compiles,
+        "warm_prep_compiles": warm.stats.prep_compiles,
     }
     print(f"coldstart cold_to_first={cold_to_first_s:.2f}s "
           f"warm_to_first={warm_to_first_s:.2f}s speedup={speedup:.1f}x "
-          f"via={rep.via} compiles={warm.stats.compiles}")
+          f"via={rep.via} compiles={warm.stats.compiles} "
+          f"prep_compiles={warm.stats.prep_compiles}")
     warm_ok = (
         rep.restored and warm.stats.compiles == 0
+        and warm.stats.prep_compiles == 0
         and speedup >= WARM_SPEEDUP_MIN
     )
     out["gates"]["warm_restart"] = warm_ok
     if not warm_ok:
         failures.append(
             f"warm restart: restored={rep.restored} via={rep.via!r} "
-            f"compiles={warm.stats.compiles} speedup={speedup:.1f}x "
+            f"compiles={warm.stats.compiles} "
+            f"prep_compiles={warm.stats.prep_compiles} speedup={speedup:.1f}x "
             f"(need 0 compiles and >= {WARM_SPEEDUP_MIN}x)"
         )
     # identical restored history -> identical rung choices -> the restored

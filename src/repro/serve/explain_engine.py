@@ -25,9 +25,9 @@ hot compiled program. This engine makes that true under real traffic:
     so ``ig``/``noise_tunnel``/``expected_grad`` share one warmed riemann set
     and ``idgi`` compiles its own — either way the shape set stays closed.
     Path-ensemble methods are served by replicating each request
-    ``n_samples``× at plan time and perturbing rows in embedding space at
-    batch-construction time (outside the compiled program), then averaging
-    each request's contiguous sample results;
+    ``n_samples``× at plan time and perturbing rows in embedding space in
+    the bucket's prep program (outside the attribution executables), then
+    averaging each request's contiguous sample results;
   * an optional device mesh shards the folded (batch × step) stage-2 axis
     via the pjit specs in ``repro.sharding`` (DESIGN.md §9): every bucket /
     start / hop executable is compiled with ``NamedSharding``s resolved per
@@ -96,7 +96,6 @@ from repro.serve.spans import (
     COMPILE,
     GATHER,
     INPUTS,
-    MASKS,
     READBACK,
     REFINE,
     WAIT,
@@ -214,6 +213,12 @@ class EngineStats:
     # span name -> (count, seconds), each span's wall time including the
     # spans nested inside it (serve.spans; docs/serving.md)
     spans: dict = field(default_factory=dict)
+    # bucket prep programs compiled (``ExplainEngine._bucket_inputs``): one
+    # per argument shape, flat at steady state; not executable-cache misses
+    # and not in any bucket row, so ``compiles``/``compile_s`` stay the
+    # executables'; the ``repro.engine.compile`` span counts both
+    prep_compiles: int = 0
+    prep_compile_s: float = 0.0
 
     @property
     def hit_rate(self) -> float:
@@ -397,6 +402,12 @@ class ExplainEngine:
         self.model = model_for(cfg)
         self.stats = EngineStats()
         self._cache: dict[tuple, Any] = {}  # key -> compiled executable
+        # ("prep", argument shapes) -> compiled bucket prep program
+        # (_bucket_inputs). The key holds shapes alone: ``_prep`` closes over
+        # ``_spec``, ``sigma``, ``sample_seed``, ``n_masks`` and ``pad_id``,
+        # which must stay fixed after __init__. serve.warm_state saves and
+        # restores these beside the executables.
+        self._prep_cache: dict[tuple, Any] = {}
         # content-addressed attribution cache (serve.result_cache): an int
         # is a byte budget, a ResultCache instance is shared/injected, None
         # (default) disables — repeat requests then always recompute
@@ -423,8 +434,10 @@ class ExplainEngine:
         # per-rung Explainer variants for hop-zero starts (m0 != m)
         self._explainers_m: dict[int, Explainer] = {}
         # (fn, arg ShapeDtypeStructs, donate_argnums) per compiled key —
-        # what warm-start persistence needs to jax.export the set
+        # what warm-start persistence needs to jax.export the set; the prep
+        # programs' in a dict of their own
         self._export_info: dict[tuple, tuple] = {}
+        self._prep_export_info: dict[tuple, tuple] = {}
         self._model_fp: Optional[str] = None
         # params replicated over the mesh, placed on first sharded call
         self._mesh_params: Any = None
@@ -846,72 +859,104 @@ class ExplainEngine:
 
     # -- serving -----------------------------------------------------------
 
+    def _prep(self, params, x, targets, lens, mask, idx, f_x=None) -> tuple:
+        """A bucket's host arrays -> the attribution executable's arguments.
+
+        Traced once per bucket shape into the bucket's prep program
+        (``_bucket_inputs``), so the embedding, the ensemble draw and the mask
+        draw run as one compiled call instead of one device op at a time.
+        ``x`` is the (B, S) int token batch or the (B, S, *F) feature batch;
+        ``idx`` (B,) uint32 holds each row's own request index (batch-pad rows
+        repeat the last real one). What it returns is the branch the engine's
+        method takes: ``(embeds, baseline, aux, mask)``, then ``f_x`` for a
+        probe-reuse bucket, or the drawn masks ``z`` (and LIME's ``groups``)
+        for a forward-only method."""
+        spec = self._spec
+        S = mask.shape[1]
+        aux = {"target": targets, "pos": lens - 1}
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            embeds = self.model.embed_inputs(params, {"tokens": x})
+            # PAD-token embedding, not zeros: RMSNorm backbones are scale-
+            # invariant through their first norm, so a ray through the origin
+            # has (near-)zero gradient a.e. and completeness can never
+            # converge.
+            baseline = pad_embedding(
+                params["embed"]["embedding"], embeds, pad_id=self.pad_id
+            )
+        else:
+            # feature-space requests (ViT patches): the IG path interpolates
+            # embedded features toward the embedded BLACK image (an affine
+            # patch projection maps the paper's pixel-space straight line to
+            # exactly this embedding-space line; the bias+posemb offset is
+            # shared, so it is off-path-direction and the baseline gradient
+            # is non-degenerate — unlike a zero embedding)
+            embeds = self.model.embed_features(params, x)
+            baseline = self.model.embed_features(params, jnp.zeros_like(x))
+        # Each row's key is a pure function of ITS OWN (expanded) request
+        # index, NOT a call counter and NOT the batch shape: replayed traffic
+        # must draw the same ensemble and masks so its escalation path — and
+        # therefore the set of hop shapes it touches — replays exactly (zero
+        # recompiles), and a mesh-padded bucket (B rounded up to the dp
+        # multiple, DESIGN.md §9) must draw the same per-row samples as the
+        # single-device bucket (sharded parity).
+        keys = jax.vmap(lambda i: perturb.request_key(self.sample_seed, S, i))(idx)
+        if spec.expand is not None:
+            # path-ensemble perturbation in embedding space: rows are already
+            # replicated requests (see explain()), so each row draws its own
+            # iid sample here — in the prep program, OUTSIDE the attribution
+            # executables, which is what keeps ensemble methods on the shared
+            # riemann executables
+            e2, b2 = jax.vmap(
+                lambda e, b, k: spec.expand(e[None], b[None], k, 1, self.sigma)
+            )(embeds, baseline, keys)
+            embeds, baseline = e2[:, 0], b2[:, 0]
+        args = (embeds, baseline, aux, mask)
+        if spec.forward_only:
+            pm = perturb.draw_masks(spec.name, keys, S, self.n_masks)
+            return args + ((pm.z,) if pm.groups is None else (pm.z, pm.groups))
+        # probe-reuse bucket (docs/serving.md): the donated endpoint rides as
+        # a trailing (B,) f32 argument. plan_buckets never mixes known-fx and
+        # self-probing requests in one bucket, and explain() strips f_x for
+        # ensemble methods before planning.
+        return args if f_x is None else (*args, f_x)
+
     def _bucket_inputs(self, bb: BucketBatch) -> tuple:
+        """The attribution executable's arguments for one bucket, built on
+        the device by the bucket's prep program (``_prep``).
+
+        One prep program per argument shape is compiled on first use and
+        cached; its compile runs under the ``repro.engine.compile`` span and
+        is counted in ``EngineStats.prep_compiles``/``prep_compile_s``, not
+        in the executable cache's misses or the bucket rows. The returned
+        arguments are dispatched, not waited for: the program's device time
+        lands in the executable call's ``repro.engine.wait``. A forward-only
+        bucket ignores a donated ``f_x``: callers strip it before planning,
+        and the masks take its place in the argument tuple."""
+        B, n = bb.bucket[0], len(bb.indices)
+        idx = np.asarray([*bb.indices, *[bb.indices[-1]] * (B - n)], np.uint32)
+        host = [
+            bb.tokens if bb.features is None else bb.features,
+            np.asarray(bb.targets, np.int32),
+            np.asarray(bb.lens, np.int32),
+            bb.mask,
+            idx,
+        ]
+        if bb.f_x is not None and not self._spec.forward_only:
+            host.append(np.asarray(bb.f_x, np.float32))
+        key = ("prep", tuple((a.shape, a.dtype.str) for a in host))
+        prep = self._prep_cache.get(key)
+        if prep is None:
+            self.stats.prep_compiles += 1
+            sds = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (self.params, *host)
+            )
+            with span(self.stats, COMPILE, prep=True) as sp:
+                prep = jax.jit(self._prep).lower(*sds).compile()
+            self.stats.prep_compile_s += sp.seconds
+            self._prep_cache[key] = prep
+            self._prep_export_info[key] = (self._prep, sds, ())
         with span(self.stats, INPUTS, bucket=bb.bucket):
-            tokens = jnp.asarray(bb.tokens)
-            aux = {
-                "target": jnp.asarray(bb.targets, jnp.int32),
-                "pos": jnp.asarray(bb.lens - 1, jnp.int32),
-            }
-            mask = jnp.asarray(bb.mask)
-            if bb.features is not None:
-                # feature-space requests (ViT patches): the IG path interpolates
-                # embedded features toward the embedded BLACK image (an affine
-                # patch projection maps the paper's pixel-space straight line to
-                # exactly this embedding-space line; the bias+posemb offset is
-                # shared, so it is off-path-direction and the baseline gradient
-                # is non-degenerate — unlike a zero embedding)
-                feats = jnp.asarray(bb.features)
-                embeds = self.model.embed_features(self.params, feats)
-                baseline = self.model.embed_features(
-                    self.params, jnp.zeros_like(feats)
-                )
-            else:
-                embeds = self.model.embed_inputs(self.params, {"tokens": tokens})
-                # PAD-token embedding, not zeros: RMSNorm backbones are scale-
-                # invariant through their first norm, so a ray through the origin
-                # has (near-)zero gradient a.e. and completeness can never
-                # converge.
-                baseline = pad_embedding(
-                    self.params["embed"]["embedding"], embeds, pad_id=self.pad_id
-                )
-            if self._spec.expand is not None:
-                # path-ensemble perturbation in embedding space: rows are already
-                # replicated requests (see explain()), so each row draws its own
-                # iid sample here — OUTSIDE the compiled program, which is what
-                # keeps ensemble methods on the shared riemann executables. Each
-                # row's key is a pure function of ITS OWN (expanded) request
-                # index, NOT a call counter and NOT the batch shape: replayed
-                # traffic must draw the same ensemble so its escalation path —
-                # and therefore the set of hop shapes it touches — replays
-                # exactly (zero recompiles), and a mesh-padded bucket (B rounded
-                # up to the dp multiple, DESIGN.md §9) must draw the same
-                # per-row ensemble as the single-device bucket (sharded parity).
-                # Batch-pad rows duplicate the last real request's index, so
-                # their (discarded) noise duplicates too.
-                base = jax.random.fold_in(
-                    jax.random.PRNGKey(self.sample_seed), bb.bucket[1]
-                )
-                padded = list(bb.indices)
-                padded += [padded[-1]] * (bb.bucket[0] - len(padded))
-                # one vmapped draw, not a per-row loop: same per-row streams
-                # (each row's draw depends only on its own key), O(1) dispatches
-                keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
-                    jnp.asarray(padded, jnp.uint32)
-                )
-                e2, b2 = jax.vmap(
-                    lambda e, b, k: self._spec.expand(
-                        e[None], b[None], k, 1, self.sigma
-                    )
-                )(embeds, baseline, keys)
-                embeds, baseline = e2[:, 0], b2[:, 0]
-            if bb.f_x is not None:
-                # probe-reuse bucket (docs/serving.md): the donated endpoint rides
-                # as a trailing (B,) f32 argument. plan_buckets never mixes
-                # known-fx and self-probing requests in one bucket, and explain()
-                # strips f_x for ensemble methods before planning.
-                return embeds, baseline, aux, mask, jnp.asarray(bb.f_x, jnp.float32)
-            return embeds, baseline, aux, mask
+            return prep(self.params, *host)
 
     def _run_bucket(self, bb: BucketBatch) -> Any:
         args = self._bucket_inputs(bb)
@@ -936,8 +981,8 @@ class ExplainEngine:
     def _fwd_fn_at(self, cfg: HotpathConfig):
         """The compiled forward-evaluator unit: embeds + masks -> scores.
 
-        Masks arrive as RUNTIME data drawn at plan time (the expansion
-        happens outside the compiled program, mirroring the path-ensemble
+        Masks arrive as RUNTIME data drawn by the bucket's prep program
+        (``_prep``, outside this executable, mirroring the path-ensemble
         contract), so one executable per (bucket, method, P) serves all
         replayed traffic. LIME's group map and ragged-group validity are
         pure in (bucket shape, mask) and recomputed inside the program —
@@ -972,35 +1017,12 @@ class ExplainEngine:
 
         return fwd
 
-    def _fwd_bucket_inputs(self, bb: BucketBatch) -> tuple:
-        """Fixed-m inputs plus the plan-time mask draw.
-
-        Every row's masks come from ``perturb.request_key`` — pure in its
-        own request index, exactly the ensemble-expansion discipline: replay
-        is bit-identical, batch-pad rows duplicate the last real row's
-        masks, and a mesh-padded bucket draws the same per-row masks as the
-        single-device one."""
-        # callers strip f_x before planning (explain()/the scheduler flush);
-        # slice defensively so a stray endpoint can't widen the arg tuple
-        embeds, baseline, aux, mask = self._bucket_inputs(bb)[:4]
-        S = bb.bucket[1]
-        padded = list(bb.indices)
-        padded += [padded[-1]] * (bb.bucket[0] - len(padded))
-        with span(self.stats, MASKS, bucket=bb.bucket):
-            keys = jax.vmap(
-                lambda i: perturb.request_key(self.sample_seed, S, i)
-            )(jnp.asarray(padded, jnp.uint32))
-            pm = perturb.draw_masks(self._spec.name, keys, S, self.n_masks)
-        if pm.groups is not None:
-            return embeds, baseline, aux, mask, pm.z, pm.groups
-        return embeds, baseline, aux, mask, pm.z
-
     def _run_bucket_fwd(self, bb: BucketBatch) -> Any:
         """One forward-evaluator bucket call -> ``perturb.PerturbResult``
         (attributions are per POSITION (B, S), already exactly zero at
         pads). Its own executable key class: no schedule, no n_int — the
         mask budget P and the scan chunk are the program shape."""
-        args = self._fwd_bucket_inputs(bb)
+        args = self._bucket_inputs(bb)
         bs = self.stats.bucket(bb.bucket)
         key = ("fwd", bb.bucket, self._spec.accum, self.n_masks,
                self._fwd_chunk(), self.use_kernels, self.attn, self._mesh_key)

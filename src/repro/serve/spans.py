@@ -28,8 +28,7 @@ STEP = "repro.sched.step"  # one work item of MixedScheduler.step
 ITEM = "repro.sched.item"  # its retried run (what the straggler monitor sees)
 FLUSH = "repro.sched.flush"  # pending explains planned into bucket items
 DELIVER = "repro.sched.deliver"  # results converted, cached, delivered
-INPUTS = "repro.engine.inputs"  # a bucket's embeddings, host to device
-MASKS = "repro.engine.masks"  # a forward-only bucket's mask draw
+INPUTS = "repro.engine.inputs"  # a bucket's prep call dispatched; its device time is WAIT's
 COMPILE = "repro.engine.compile"  # an executable-cache miss, refusals included
 CALL = "repro.engine.call"  # one executable call: dispatch and wait
 WAIT = "repro.engine.wait"  # the wait for its result inside CALL
@@ -38,8 +37,8 @@ GATHER = "repro.ladder.gather"  # survivors re-padded, hop arguments built
 READBACK = "repro.ladder.readback"  # results read back, survivors selected
 AUTOTUNE = "repro.autotune.call"  # one timed candidate call of the autotuner
 
-NAMES = (SUBMIT, STEP, ITEM, FLUSH, DELIVER, INPUTS, MASKS, COMPILE, CALL, WAIT,
-         REFINE, GATHER, READBACK, AUTOTUNE)
+NAMES = (SUBMIT, STEP, ITEM, FLUSH, DELIVER, INPUTS, COMPILE, CALL, WAIT, REFINE,
+         GATHER, READBACK, AUTOTUNE)
 
 
 class span:

@@ -1,11 +1,12 @@
 """Warm-start persistence: a restarted engine explains with zero compiles.
 
 ``ExplainEngine`` reaches steady state by AOT-compiling one executable per
-(bucket, method-class, schedule, m, ...) key — seconds each. On restart that
-whole set is gone. This module persists it (ISSUE 10), alongside the
-autotune entries and the adaptive hop-zero δ-history, with the checkpoint
-manager's atomicity discipline (``checkpoint.manager.atomic_dir``: tmp-dir
-write, per-file sha256 manifest, one ``os.replace``).
+(bucket, method-class, schedule, m, ...) key — seconds each — and one bucket
+prep program per argument shape. On restart that whole set is gone. This
+module persists it (ISSUE 10), alongside the autotune entries and the
+adaptive hop-zero δ-history, with the checkpoint manager's atomicity
+discipline (``checkpoint.manager.atomic_dir``: tmp-dir write, per-file
+sha256 manifest, one ``os.replace``).
 
 Two serialized forms of the executable set, tried in order at restore:
 
@@ -138,16 +139,23 @@ def save_warm_state(engine: Any, directory: str) -> str:
     # so restore→save carries the original blobs forward instead of dropping
     # the entry — the cycle must never shrink the warm state
     carried = getattr(engine, "_warm_saved", {"native": {}, "portable": {}})
+    # the bucket prep programs ride beside the executables, under their
+    # ("prep", shapes) keys; no prep program is sharded
+    entries = [
+        *engine._cache.items(),
+        *((key, (prep, None)) for key, prep in engine._prep_cache.items()),
+    ]
+    export_info = {**engine._export_info, **engine._prep_export_info}
     # None once any executable refuses native serialization: the set then
     # restores through the portable form alone
     native: Optional[list[dict]] = []
     portable: list[dict] = []
     skipped = 0
-    for key, (compiled, shardings) in engine._cache.items():
+    for key, (compiled, shardings) in entries:
         if shardings is not None:
             skipped += 1
             continue
-        info = engine._export_info.get(key)
+        info = export_info.get(key)
         if info is None:
             kept = False
             if key in carried["native"] and native is not None:
@@ -297,7 +305,7 @@ def load_warm_state(engine: Any, directory: str) -> WarmRestoreReport:
                 stacklevel=2,
             )
         if restored:
-            engine._cache.update(restored)
+            _install(engine, restored)
             _stash_blobs(engine, directory, with_native=True)
             return WarmRestoreReport(
                 restored=True, via="native", executables=len(restored)
@@ -312,13 +320,24 @@ def load_warm_state(engine: Any, directory: str) -> WarmRestoreReport:
             # donation is not re-requested here: the exported module is
             # re-compiled by XLA anyway and donation is a perf hint only
             restored[b["key"]] = (jax.jit(exp.call).lower(*sds).compile(), None)
-        engine._cache.update(restored)
+        _install(engine, restored)
         _stash_blobs(engine, directory, with_native=False)
         return WarmRestoreReport(
             restored=True, via="export", executables=len(restored)
         )
     except Exception as e:  # noqa: BLE001 — never let a bad blob kill serving
         return _cold(f"portable restore failed ({e})")
+
+
+def _install(engine: Any, restored: dict) -> None:
+    """Put restored entries where the engine looks them up: prep programs
+    (``("prep", shapes)`` keys) in ``_prep_cache``, executables in
+    ``_cache``."""
+    for key, (compiled, shardings) in restored.items():
+        if key[0] == "prep":
+            engine._prep_cache[key] = compiled
+        else:
+            engine._cache[key] = (compiled, shardings)
 
 
 def _stash_blobs(engine: Any, directory: str, *, with_native: bool) -> None:

@@ -10,19 +10,26 @@ The guarantees the serving refactor rests on:
   (d) every attribution method in the MethodSpec registry serves through the
       engine — fixed-m AND adaptive — with zero steady-state recompiles
       (replayed traffic is pure cache hits), and the per-row compiled unit
-      matches the core Explainer on the same embeddings.
+      matches the core Explainer on the same embeddings;
+  (e) each bucket's compiled prep program returns the arguments the eager
+      per-op composition built, compiles once per argument shape, and draws
+      per-row samples that do not depend on the bucket's padding.
 """
+import copy
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS, reduced
-from repro.core import schedule
+from repro.configs.vit import reduced_vit
+from repro.core import perturb, schedule
 from repro.core.api import Explainer
 from repro.core.baselines import pad_embedding
 from repro.core.methods import METHODS
-from repro.models.registry import Model
+from repro.models.registry import Model, model_for
 from repro.serve import ExplainEngine, ExplainRequest
 from repro.serve.batching import bucket_for, plan_buckets, pow2_ladder
 
@@ -178,8 +185,10 @@ def test_method_zoo_zero_steady_state_recompiles(lm, method):
     out = eng.explain(_requests(cfg, MIXED_LENS, seed=11))
     misses = eng.stats.misses
     assert misses > 0
+    prep = eng.stats.prep_compiles
     out2 = eng.explain(_requests(cfg, MIXED_LENS, seed=12))
     assert eng.stats.misses == misses, f"{method} recompiled at steady state"
+    assert eng.stats.prep_compiles == prep > 0
     for o in out + out2:
         assert np.isfinite(o["token_scores"]).all()
         assert np.isfinite(o["delta"]) and np.isfinite(o["f_x"])
@@ -234,6 +243,173 @@ def test_ensemble_engine_result_is_sample_mean(lm):
     out_ig = base.explain(reqs)
     for a, b in zip(out_nt, out_ig):
         np.testing.assert_allclose(a["token_scores"], b["token_scores"], atol=1e-4)
+
+
+# ----------------------------------------------- (e) the bucket prep program
+
+
+def _eager_bucket_inputs(eng, bb):
+    """The arguments as the engine built them before the prep program: one
+    eager device op at a time (the oracle the compiled prep must match)."""
+    tokens = jnp.asarray(bb.tokens)
+    aux = {
+        "target": jnp.asarray(bb.targets, jnp.int32),
+        "pos": jnp.asarray(bb.lens - 1, jnp.int32),
+    }
+    mask = jnp.asarray(bb.mask)
+    if bb.features is not None:
+        feats = jnp.asarray(bb.features)
+        embeds = eng.model.embed_features(eng.params, feats)
+        baseline = eng.model.embed_features(eng.params, jnp.zeros_like(feats))
+    else:
+        embeds = eng.model.embed_inputs(eng.params, {"tokens": tokens})
+        baseline = pad_embedding(
+            eng.params["embed"]["embedding"], embeds, pad_id=eng.pad_id
+        )
+    padded = list(bb.indices)
+    padded += [padded[-1]] * (bb.bucket[0] - len(padded))
+    S = bb.bucket[1]
+    keys = jax.vmap(lambda i: perturb.request_key(eng.sample_seed, S, i))(
+        jnp.asarray(padded, jnp.uint32)
+    )
+    if eng._spec.expand is not None:
+        e2, b2 = jax.vmap(
+            lambda e, b, k: eng._spec.expand(e[None], b[None], k, 1, eng.sigma)
+        )(embeds, baseline, keys)
+        embeds, baseline = e2[:, 0], b2[:, 0]
+    args = (embeds, baseline, aux, mask)
+    if eng._spec.forward_only:
+        pm = perturb.draw_masks(eng._spec.name, keys, S, eng.n_masks)
+        return args + ((pm.z,) if pm.groups is None else (pm.z, pm.groups))
+    if bb.f_x is not None:
+        return args + (jnp.asarray(bb.f_x, jnp.float32),)
+    return args
+
+
+PREP_PATHS = {
+    # name: (model, method, donated f_x)
+    "tokens": ("lm", "ig", False),
+    "tokens-fx": ("lm", "ig", True),
+    "features": ("vit", "ig", False),
+    "features-bf16": ("vit-bf16", "idgi", False),
+    "noise_tunnel": ("lm", "noise_tunnel", False),
+    "expected_grad": ("vit", "expected_grad", False),
+    "occlusion": ("vit", "occlusion", False),
+    "rise": ("lm", "rise", False),
+    "lime": ("lm", "lime", False),
+}
+
+
+@pytest.fixture(scope="module")
+def models(lm):
+    vit = reduced_vit()
+    vit16 = replace(vit, compute_dtype="bfloat16")
+    vit_params = model_for(vit).init(KEY)
+    return {"lm": lm[::2], "vit": (vit, vit_params), "vit-bf16": (vit16, vit_params)}
+
+
+def _prep_requests(cfg, lens, *, f_x=False, seed=0):
+    rng = np.random.default_rng(seed)
+    vit = hasattr(cfg, "patch_dim")
+    return [
+        ExplainRequest(
+            tokens=(np.arange(s) if vit else rng.integers(1, cfg.vocab_size, s))
+            .astype(np.int32),
+            target=int(rng.integers(0, cfg.num_classes if vit else cfg.vocab_size)),
+            features=(
+                rng.normal(size=(s, cfg.patch_dim)).astype(np.float32) if vit else None
+            ),
+            f_x=-1.25 - s if f_x else None,
+        )
+        for s in lens
+    ]
+
+
+def _prep_bucket(models, path, **plan_kw):
+    model, method, f_x = PREP_PATHS[path]
+    cfg, params = models[model]
+    eng = _engine(cfg, params, method=method, seq_buckets=(8, 16), sample_seed=5)
+    reqs = _prep_requests(cfg, (13, 9, 11), f_x=f_x)  # 3 rows -> B=4: one pad row
+    (bb,) = plan_buckets(reqs, seq_buckets=eng.seq_buckets, **plan_kw)
+    return eng, bb
+
+
+@pytest.mark.parametrize("path", sorted(PREP_PATHS))
+def test_prep_program_matches_eager_composition(models, path):
+    """The compiled prep returns the eager composition's arguments, leaf for
+    leaf and bit for bit. A path ensemble's noisy rows are the one exception:
+    the compiled ``x + σ·n`` may fuse into one rounding where eager rounds
+    twice, so they agree to that rounding (the random draws are the same)."""
+    eng, bb = _prep_bucket(models, path)
+    assert bb.bucket == (4, 16)
+    got = jax.tree.leaves(eng._bucket_inputs(bb))
+    want = jax.tree.leaves(_eager_bucket_inputs(eng, bb))
+    assert len(got) == len(want)
+    noisy = {"noise_tunnel": 0, "expected_grad": 1}.get(path)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.shape == w.shape and g.dtype == w.dtype, i
+        g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
+        if i == noisy:
+            eps = float(jnp.finfo(eng.model.cfg.compute_dtype).eps)
+            quiet = copy.copy(eng)  # the same engine drawing no noise
+            quiet.sigma = 0.0
+            quiet._prep_cache = {}  # its programs would draw the noise
+            x = np.asarray(jax.tree.leaves(_eager_bucket_inputs(quiet, bb))[i], np.float64)
+            bound = 4 * eps * (np.abs(w) + np.abs(w - x))
+            assert np.all(np.abs(g - w) <= bound), i
+            assert not np.array_equal(w, x)  # the noise was drawn
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=f"leaf {i}")
+    assert eng.stats.prep_compiles == 1
+
+
+@pytest.mark.parametrize("path", ["tokens", "features", "occlusion", "lime"])
+def test_prep_second_pass_compiles_nothing(models, path):
+    """One prep program per argument shape: a second pass over the same
+    buckets reuses them, and each compile is charged to the compile span
+    and to the prep counters, never to the executable cache or a bucket."""
+    model, method, _ = PREP_PATHS[path]
+    cfg, params = models[model]
+    eng = _engine(cfg, params, method=method, seq_buckets=(8, 16))
+    plan = plan_buckets(
+        _prep_requests(cfg, (3, 5, 11, 9, 16, 7)), seq_buckets=eng.seq_buckets,
+        batch_buckets=eng.batch_buckets,
+    )
+    shapes = {bb.bucket for bb in plan}
+    assert len(shapes) > 1
+    first = [eng._bucket_inputs(bb) for bb in plan]
+    assert eng.stats.prep_compiles == len(shapes)
+    assert eng.stats.spans["repro.engine.compile"][0] == len(shapes)
+    assert eng.stats.misses == eng.stats.hits == 0 and not eng.stats.buckets
+    assert eng.stats.prep_compile_s == pytest.approx(
+        eng.stats.spans["repro.engine.compile"][1]
+    )
+    second = [eng._bucket_inputs(bb) for bb in plan]
+    assert eng.stats.prep_compiles == len(shapes)
+    assert eng.stats.spans["repro.engine.inputs"][0] == 2 * len(plan)
+    for a, b in zip(first, second):
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("path", ["noise_tunnel", "occlusion", "rise", "lime"])
+def test_prep_mesh_padded_bucket_draws_same_rows(models, path):
+    """A bucket padded up to a data-parallel multiple (B 4 -> 8) draws the
+    same per-row masks and noise for its real rows as the unpadded one."""
+    eng, bb = _prep_bucket(models, path)
+    _, wide = _prep_bucket(models, path, batch_multiple=8)
+    assert (bb.bucket, wide.bucket) == ((4, 16), (8, 16))
+    n = len(bb.indices)
+    got = jax.tree.leaves(eng._bucket_inputs(wide))
+    want = jax.tree.leaves(eng._bucket_inputs(bb))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g)[:n], np.asarray(w)[:n])
+    # pad rows repeat the last real row's draw
+    for g in got:
+        g = np.asarray(g)
+        for row in range(n, wide.bucket[0]):
+            np.testing.assert_array_equal(g[row], g[n - 1])
+    assert eng.stats.prep_compiles == 2
 
 
 # ----------------------------------------------------------- bucket planning

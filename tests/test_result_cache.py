@@ -228,6 +228,7 @@ def test_warm_restore_zero_compiles_and_bit_identical(lm, warmed):
         assert rep.via == "export"
     replay = eng2.explain(reqs)
     assert eng2.stats.compiles == 0, "restored engine must never compile"
+    assert eng2.stats.prep_compiles == 0, "restored engine must never compile"
     for a, b in zip(out, replay):
         np.testing.assert_array_equal(a["token_scores"], b["token_scores"])
         assert a["delta"] == b["delta"]
@@ -261,7 +262,7 @@ def test_warm_restore_context_mismatch_falls_back_cold(lm, warmed):
         warnings.simplefilter("always")
         rep = load_warm_state(eng2, td)
     assert not rep.restored and "context" in rep.reason
-    assert eng2._cache == {}
+    assert eng2._cache == {} and eng2._prep_cache == {}
 
 
 def test_warm_restore_save_cycle_preserves_executables(lm, warmed, tmp_path):
@@ -278,12 +279,16 @@ def test_warm_restore_save_cycle_preserves_executables(lm, warmed, tmp_path):
     save_warm_state(eng2, resaved)
     with open(os.path.join(resaved, "manifest.json")) as fh:
         n = json.load(fh)["n_executables"]
-    assert n == len(eng2._cache) > 0, "restore->save shrank the warm state"
+    # executables and bucket prep programs alike
+    assert eng2._prep_cache
+    assert n == len(eng2._cache) + len(eng2._prep_cache) > 0, (
+        "restore->save shrank the warm state"
+    )
     eng3 = _engine(cfg, params, result_cache=1 << 20)
     rep = load_warm_state(eng3, resaved)
     assert rep.restored and rep.executables == n
     replay = eng3.explain(reqs)
-    assert eng3.stats.compiles == 0
+    assert eng3.stats.compiles == 0 and eng3.stats.prep_compiles == 0
     for a, b in zip(out, replay):
         np.testing.assert_array_equal(a["token_scores"], b["token_scores"])
 
