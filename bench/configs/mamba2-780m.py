@@ -45,6 +45,23 @@ def ref_len(c: dict) -> int:
     return max(c["engine"]["seq_buckets"])
 
 
+def small(c: dict, *, control: bool = False) -> dict:
+    """The CPU stand-in of these sizes, with the same keys: the program's
+    ``reduced(mamba2-780m)`` widths, two layers, a 512-token vocabulary,
+    sequences up to 32 (two SSD chunks of 16), an engine of chunk 4 and
+    batches 1-4. The control test runs at the same size."""
+    del control
+    return dict(c, num_layers=2, d_model=64, vocab_size=512, ssm_state=16, ssm_head_dim=16,
+                ssm_chunk=16,
+                engine=dict(c["engine"], chunk=4, batch_buckets=[1, 2, 4], max_batch=4,
+                            seq_buckets=[32]))
+
+
+def layer_kinds(c: dict) -> list[str]:
+    """The kind of each layer, in order: every layer is a Mamba-2 mixer."""
+    return ["mamba2"] * c["num_layers"]
+
+
 def program_config(c: dict):
     """The program's own config object for these sizes."""
     import dataclasses

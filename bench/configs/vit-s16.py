@@ -32,6 +32,25 @@ def patch_dim(c: dict) -> int:
     return c["patch_size"] ** 2 * c["channels"]
 
 
+def small(c: dict, *, control: bool = False) -> dict:
+    """The CPU stand-in of these sizes, with the same keys: the program's
+    ``reduced_vit()`` widths on 32 px images (64 patches), two layers, an
+    engine of chunk 4 and batches 1-4. ``control`` gives the control test's
+    size instead: published widths and depth on 64 px images (16 patches),
+    the published engine."""
+    if control:
+        return dict(c, image_size=64, engine=dict(c["engine"], seq_buckets=[16]))
+    return dict(c, image_size=32, patch_size=4, num_classes=10, num_layers=2, d_model=64,
+                num_heads=4, d_ff=128,
+                engine=dict(c["engine"], chunk=4, batch_buckets=[1, 2, 4], max_batch=4,
+                            seq_buckets=[64]))
+
+
+def layer_kinds(c: dict) -> list[str]:
+    """The kind of each layer, in order: every block is alike."""
+    return ["attention+mlp"] * c["num_layers"]
+
+
 def program_config(c: dict):
     """The program's own config object for these sizes."""
     from repro.configs.vit import VitConfig

@@ -8,7 +8,6 @@ COMPILE = "repro.engine.compile"
 CALL = "repro.engine.call"
 WAIT = "repro.engine.wait"
 INPUTS = "repro.engine.inputs"
-MASKS = "repro.engine.masks"
 LADDER = ("repro.ladder.refine", "repro.ladder.gather", "repro.ladder.readback")
 
 
@@ -28,10 +27,6 @@ def seconds(spans, *names):
 
 def count(spans, name):
     return spans.get(name, (0, 0.0))[0]
-
-
-def is_mamba2(ctx):
-    return ctx.sizes.get("name") == "mamba2-780m"
 
 
 def host_ms_per_step(ctx):

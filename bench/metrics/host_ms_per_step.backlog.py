@@ -4,7 +4,6 @@ from bench.harness.spec import metric_reader
 
 
 def read(ctx):
-    spans = metric_reader("_spans")
-    if ctx.open_loop or spans.is_mamba2(ctx):
+    if ctx.open_loop:
         return None
-    return spans.host_ms_per_step(ctx)
+    return metric_reader("_spans").host_ms_per_step(ctx)

@@ -1,7 +1,7 @@
 """Engine batching under open-loop traffic: milliseconds of input
-preparation (embedding, host to device, and a forward-only bucket's mask
-draw) per executable call in the window, from the program's span counters
-(see _spans.py)."""
+preparation (the dispatch of the bucket's prep program, which embeds the
+inputs and draws a forward-only bucket's masks) per executable call in the
+window, from the program's span counters (see _spans.py)."""
 from bench.harness.spec import metric_reader
 
 
@@ -12,4 +12,4 @@ def read(ctx):
     spans = s.window(ctx)
     if spans is None or not s.count(spans, s.CALL):
         return None
-    return 1e3 * s.seconds(spans, s.INPUTS, s.MASKS) / s.count(spans, s.CALL)
+    return 1e3 * s.seconds(spans, s.INPUTS) / s.count(spans, s.CALL)

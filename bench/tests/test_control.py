@@ -2,8 +2,9 @@
 (float8 weight matmuls for these bfloat16 configurations) put in the
 program's place -- is refused by each cell's own limits. On the chip the
 control was read at the cells' own sizes (PERF.md); here at a size a test
-run can hold, on three seeds: the ViT at its published widths and depth on
-64 px images (16 patches), the Mamba-2 at the small test size."""
+run can hold, on three seeds, at the size each configuration's ``small``
+gives for the control (the ViT at its published widths and depth on 64 px
+images, the Mamba-2 at its CPU stand-in)."""
 import jax
 import pytest
 
@@ -15,11 +16,8 @@ from bench.tests import small
 @pytest.mark.parametrize("seed", [2**31 + 101, 7, 2**32 + 3])
 def test_control_is_not_correct(name, seed):
     info = spec.cell(small.BM, name)
-    c = small.cell(name)
+    c = small.cell(name, control=True)
     sizes, traffic, model = c["sizes"], c["traffic"], info["model"]
-    if model.KIND == "image":
-        sizes = dict(info["sizes"], image_size=64)
-        sizes["engine"] = dict(sizes["engine"], seq_buckets=[16])
     limits = runner.limits_for(name)["limits"]
     rng, key = tr.rngs(seed)
     key = jax.random.PRNGKey(key)

@@ -17,7 +17,8 @@ def test_cell_files_found_by_name(cell):
     sizes, model = info["sizes"], info["model"]
     assert sizes["name"] == info["workload"]["config"]
     for fn in ("program_config", "init_params", "make_inputs", "ref_inputs", "logprob",
-               "flops_forward", "flops_vjp", "flops_embed", "param_bytes"):
+               "flops_forward", "flops_vjp", "flops_embed", "param_bytes", "small",
+               "layer_kinds"):
         assert callable(getattr(model, fn)), fn
     assert model.CONTROL in ("bf16", "fp8")
     assert info["traffic"]["arrivals"] in ("open", "closed")
